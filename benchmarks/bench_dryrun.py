@@ -19,7 +19,8 @@ ART = "benchmarks/artifacts/dryrun"
 def _ensure():
     if len(glob.glob(os.path.join(ART, "*.json"))) >= 64:
         return
-    env = dict(os.environ, PYTHONPATH="src")
+    # CPU only: the parent may already hold the chip
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     subprocess.run([sys.executable, "-m", "repro.launch.dryrun",
                     "--arch", "all", "--shape", "all", "--mesh", "both"],
                    env=env, check=True, timeout=7200)

@@ -74,8 +74,6 @@ def run_cell(arch: str, shape_name: str) -> dict:
 
     tot = executed_totals(compiled.as_text())
     raw = compiled.cost_analysis() or {}
-    if isinstance(raw, (list, tuple)):      # older jax: one dict per device
-        raw = raw[0] if raw else {}
     mem = compiled.memory_analysis()
 
     t_c = tot["flops"] / PEAK_FLOPS
@@ -137,11 +135,13 @@ def sweep(archs=None, out_dir=ART):
 def run() -> list[dict]:
     """benchmarks.run entry: executes the sweep in a SUBPROCESS (the 512
     fake devices must be pinned before jax init, and sibling benches have
-    already initialized jax in this process), then reads the artifacts."""
+    already initialized jax in this process), then reads the artifacts.
+    The child is held to the CPU: a parent that has touched JAX holds the
+    chip, so a child that opened the TPU would fail or hang."""
     import glob
     import subprocess
     import sys
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=512",
                PYTHONPATH="src:.")
     subprocess.run([sys.executable, "-m", "benchmarks.bench_roofline"],

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 This proves the distribution config is coherent without hardware: 512
@@ -15,6 +12,9 @@ Per cell it records (benchmarks/artifacts/dryrun/<cell>.json):
     for all-gather / all-reduce / reduce-scatter / all-to-all /
     collective-permute) — the roofline's collective term.
 
+The entry point pins the CPU backend with 512 host devices before JAX
+starts a backend; importing this module changes no flag.
+
 Usage:
   python -m repro.launch.dryrun --arch all --shape all --mesh both
   python -m repro.launch.dryrun --arch whisper-tiny --shape train_4k \
@@ -23,6 +23,7 @@ Usage:
 
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -96,8 +97,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
             if v is not None:
                 mem_d[k] = int(v)
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):     # older jax: one dict per device
-        cost = cost[0] if cost else {}
     cost_d = {k: float(v) for k, v in cost.items()
               if isinstance(v, (int, float)) and (
                   "flops" in k or "bytes" in k or "utilization" in k.lower()
@@ -120,6 +119,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
 
 
 def main(argv=None):
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    jax.config.update("jax_platforms", "cpu")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

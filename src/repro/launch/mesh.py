@@ -15,17 +15,25 @@ same specs.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: shardings come from in_shardings and propagation, as the
+    # launch code was written for (Explicit axes reject plain gathers)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
-    """Degenerate 1x1 mesh for CPU smoke runs of the same launch code."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    """1x1 mesh on the default device: the one-chip mesh for serving and
+    training (and for CPU runs of the same launch code)."""
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

@@ -31,11 +31,14 @@ injects replica kills / degraded DMA clocks / stragglers from a
 deterministic FaultSchedule — a killed replica's tenants are re-admitted
 elsewhere with zero requests lost.
 
-Runs reduced configs end-to-end on CPU (1x1 mesh); the pod-mesh serving
-cells are proven by the dry-run.
+Serves on a 1x1 mesh: one TPU chip, or the CPU at ``reduced()`` size;
+``--full`` takes the published config (olmo-1b fits one v5e chip). The
+pod-mesh serving cells are proven by the dry-run.
 
   PYTHONPATH=src python -m repro.launch.serve --arch codeqwen1.5-7b \
       --batch 4 --prompt-len 32 --gen 16
+  PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b --full \
+      --mode engine --batch 8 --prompt-len 128 --gen 64
 """
 
 from __future__ import annotations
@@ -57,6 +60,7 @@ from ..runtime import (Engine, EngineConfig, ModelPool, PoolConfig,
                        shifting_mix_trace, vlm_extras_fn)
 from . import sharding as sh
 from .cli import add_streaming_args
+from .compile_cache import use_compile_cache
 from .mesh import make_host_mesh, make_production_mesh
 from .steps import make_prefill_step, make_serve_step
 
@@ -134,6 +138,13 @@ def run_engine(cfg, params, args):
     assert done, "no requests completed"
     print("ok")
     return 0
+
+
+def init_sharded_params(cfg, mesh, seed: int):
+    """Seeded random parameters, placed on ``mesh`` by the sharding rules."""
+    params = get_model(cfg).init_params(cfg, jax.random.PRNGKey(seed))
+    p_spec = sh.param_pspecs(params, mesh)
+    return jax.device_put(params, sh.to_shardings(p_spec, mesh))
 
 
 def parse_zoo(spec: str) -> list[tuple[str, float]]:
@@ -334,6 +345,7 @@ def main(argv=None):
     if not args.requests:
         args.requests = 3 * args.batch
 
+    use_compile_cache()
     mesh = (make_production_mesh if args.mesh == "pod"
             else make_host_mesh)()
     if args.mode == "pool":
@@ -350,10 +362,7 @@ def main(argv=None):
         mode = "engine" if engine_backend(cfg) else "static"
 
     with mesh:
-        api = get_model(cfg)
-        params = api.init_params(cfg, jax.random.PRNGKey(args.seed))
-        p_spec = sh.param_pspecs(params, mesh)
-        params = jax.device_put(params, sh.to_shardings(p_spec, mesh))
+        params = init_sharded_params(cfg, mesh, args.seed)
         if mode == "engine":
             return run_engine(cfg, params, args)
         return run_static(cfg, params, args)

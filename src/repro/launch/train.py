@@ -2,9 +2,9 @@
 
 Wires the full stack: config -> model -> sharded step (pjit) -> data
 pipeline -> AdamW -> checkpoint manager -> fault-tolerant supervisor.
-On this CPU container it runs reduced configs on a 1x1 mesh end-to-end;
-on a pod the same code takes ``--mesh pod`` (the dry-run proves those
-cells compile).
+It runs on a 1x1 mesh (one chip, or the CPU at ``reduced()`` size)
+end-to-end; on a pod the same code takes ``--mesh pod`` (the dry-run
+proves those cells compile).
 
   PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --steps 200 \
       --seq-len 128 --batch 8 --ckpt-dir /tmp/ckpt
@@ -26,6 +26,7 @@ from ..models import get_model, layers as L
 from ..optim import adamw_init
 from ..runtime import ElasticConfig, TrainingSupervisor
 from . import sharding as sh
+from .compile_cache import use_compile_cache
 from .mesh import dp_axes, make_host_mesh, make_production_mesh
 from .steps import make_train_step
 
@@ -78,6 +79,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     mesh = {"host": make_host_mesh,
             "pod": make_production_mesh,
             "multipod": lambda: make_production_mesh(multi_pod=True)}[

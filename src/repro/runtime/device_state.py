@@ -38,12 +38,15 @@ class DeviceLoopState:
     were advanced by identical arithmetic.
     """
 
-    def __init__(self, num_slots: int, max_rows: int):
+    def __init__(self, num_slots: int, max_rows: int, sharding=None):
+        """``sharding``: where the fused step returns the loop arrays
+        (None: uncommitted), so they start where they will come back."""
         self.num_slots = num_slots
-        self.table = jnp.zeros((num_slots, max_rows), jnp.int32)
-        self.lengths = jnp.zeros((num_slots,), jnp.int32)
-        self.pending = jnp.zeros((num_slots,), jnp.int32)
-        self.remaining = jnp.zeros((num_slots,), jnp.int32)
+        arrays = (jnp.zeros((num_slots, max_rows), jnp.int32),
+                  *(jnp.zeros((num_slots,), jnp.int32) for _ in range(3)))
+        if sharding is not None:
+            arrays = jax.device_put(arrays, sharding)
+        self.table, self.lengths, self.pending, self.remaining = arrays
         self._dirty: set[int] = set(range(num_slots))
         self._row_bytes = max_rows * 4
         self._write = jax.jit(self._scatter_rows, donate_argnums=(0, 1, 2, 3))

@@ -822,6 +822,10 @@ class Engine:
         self.cfg = cfg
         self.ecfg = ecfg or EngineConfig()
         self.backend = resolve_backend(cfg)(cfg, params, self.ecfg)
+        self._home = _home_sharding(params)
+        if self._home is not None:
+            self.backend.state = jax.device_put(self.backend.state,
+                                                self._home)
         self.rng = np.random.default_rng(self.ecfg.seed)
         self._sample_batch = make_batch_sampler(
             self.rng, self.ecfg.greedy, self.ecfg.temperature)
@@ -857,7 +861,7 @@ class Engine:
         remaining = np.zeros((B,), np.int32)    # token budget left
         # device twins of the four loop arrays + the traffic ledger the
         # per-step fallback shares (so both paths report comparably)
-        ds = DeviceLoopState(B, M)
+        ds = DeviceLoopState(B, M, self._home)
 
         page_bytes = self.backend.page_bytes
         rep = EngineReport(
@@ -1146,6 +1150,18 @@ class Engine:
         rep.page_table_upload_bytes = ds.page_table_upload_bytes
         rep.wall_s = time.monotonic() - t_run
         return rep
+
+
+def _home_sharding(params):
+    """Where the jitted steps return the state they do not shard:
+    replicated on the mesh that mesh-placed ``params`` live on, else None
+    (uncommitted). Engine-made arrays start there, so a step's first
+    dispatch has the signature of every later one and compiles once."""
+    s = getattr(jax.tree.leaves(params)[0], "sharding", None)
+    if isinstance(s, jax.sharding.NamedSharding):
+        return jax.sharding.NamedSharding(s.mesh,
+                                          jax.sharding.PartitionSpec())
+    return None
 
 
 def _state_bytes(state) -> int:

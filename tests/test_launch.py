@@ -125,3 +125,24 @@ def test_host_mesh_runs_train_step():
         batch = stream.batch(0)
         p, o, m = jitted(params, opt, batch)
         assert jnp.isfinite(m["loss"])
+
+
+def test_mesh_placed_engine_compiles_each_step_once():
+    # the serve path places params on the host mesh; the engine's own
+    # state must start where the jitted steps return it, or the first
+    # dispatch of each step compiles a second signature
+    from repro.launch.serve import init_sharded_params
+    from repro.runtime import Engine, EngineConfig, poisson_trace
+    cfg = get_config("olmo-1b").reduced()
+    mesh = make_host_mesh()
+    with mesh:
+        params = init_sharded_params(cfg, mesh, 0)
+        eng = Engine(cfg, params, EngineConfig(
+            num_slots=4, page_size=8, num_pages=65, max_pages_per_seq=8,
+            prefill_bucket=8))
+        trace = poisson_trace(8, mean_interarrival=0.5, prompt_lens=(8, 16),
+                              gen_lens=(4, 12), vocab_size=cfg.vocab_size)
+        rep = eng.run(trace)
+    assert len(rep.completed) == 8
+    assert eng.backend._decode_multi._cache_size() == 1
+    assert eng.backend._prefill._cache_size() == 2      # one per bucket
