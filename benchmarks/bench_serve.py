@@ -102,7 +102,6 @@ def _row(rep, family):
         "new_tokens": s["new_tokens"],
         "decode_steps": s["decode_steps"],
         "preemptions": s["preemptions"],
-        "tokens_per_s": s["tokens_per_s"],
     }
 
 
@@ -728,8 +727,9 @@ def _fleet_tokens(rep) -> dict:
 # saturated dense decode with long generations: the steady state is pure
 # decode on full slots, exactly what horizon fusion targets. The paired
 # runs differ ONLY in the horizon (1 = legacy per-step dispatch), so the
-# dispatch/sync/upload counters and steady-state wall tokens/s isolate
-# the host-loop overhead the fusion removes.
+# dispatch/sync/upload counters isolate the host-loop traffic the fusion
+# removes. What that is worth in time is measured on the chip
+# (chipbench), not here.
 DW_SLOTS = 4
 DW_N_REQUESTS = 8
 DW_GEN_LENS = (48, 64)
@@ -751,9 +751,6 @@ def _dw_row(rep, name: str) -> dict:
         "device_dispatches": s["device_dispatches"],
         "host_syncs": s["host_syncs"],
         "page_table_upload_bytes": s["page_table_upload_bytes"],
-        "decode_wall_s": s["decode_wall_s"],
-        "compile_wall_s": s["compile_wall_s"],
-        "wall_tokens_per_s": s["tokens_per_s"],
     }
 
 
@@ -816,9 +813,6 @@ def run_decode_wall(smoke: bool = False) -> list[dict]:
     rows = [_dw_row(ps, "serve_decode_wall/per_step"),
             _dw_row(fu, "serve_decode_wall/fused")]
 
-    def tps(rep):
-        return rep.new_tokens / max(rep.decode_wall_s, 1e-9)
-
     rows.append({
         "name": "serve_decode_wall_fusion",
         "same_tokens": _pool_tokens(ps) == _pool_tokens(fu),
@@ -829,7 +823,6 @@ def run_decode_wall(smoke: bool = False) -> list[dict]:
         "upload_bytes_ratio": round(
             ps.page_table_upload_bytes
             / max(fu.page_table_upload_bytes, 1), 3),
-        "wall_tokens_per_s_ratio": round(tps(fu) / tps(ps), 3),
     })
     rows += _dw_dma(smoke)
     return rows
@@ -1096,9 +1089,6 @@ def check(rows) -> None:
             f"{d['host_sync_ratio']}x (need 5x)"
         assert d["upload_bytes_ratio"] > 1.0, \
             "fused decode shipped at least as many page-table bytes"
-        assert d["wall_tokens_per_s_ratio"] >= 2.0, \
-            f"fused decode only {d['wall_tokens_per_s_ratio']}x on " \
-            f"steady-state wall tokens/s (need 2x)"
         (dd,) = [x for x in rows if x["name"] == "serve_decode_wall_dma"]
         assert dd["copies_issued"] > 0, \
             "the device DMA channel never issued a real copy"

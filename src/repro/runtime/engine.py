@@ -37,8 +37,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..models import get_model
+from . import tracing
 from .arena import ArenaConfig, DeviceArena, partition_pages  # noqa: F401
 from .device_state import DeviceLoopState
 from .kv_pager import PagerConfig, TRASH_PAGE
@@ -122,18 +124,6 @@ def make_sampler(rng: np.random.Generator, greedy: bool,
     return sample
 
 
-def _charge_wall(rep, seen: set, key, dt: float) -> None:
-    """Charge ``dt`` for one decode dispatch: the first dispatch of each
-    jit signature pays trace+compile, so it lands in ``compile_wall_s``
-    and every later one in ``decode_wall_s`` — wall-clock throughput
-    comparisons then measure steady state, not compiler time."""
-    if key in seen:
-        rep.decode_wall_s += dt
-    else:
-        seen.add(key)
-        rep.compile_wall_s += dt
-
-
 def vlm_extras_fn(cfg, num_patches: int = 4):
     """Per-request extras generator for vlm traces (poisson_trace hook)."""
     def extras(rng: np.random.Generator) -> dict:
@@ -163,10 +153,6 @@ class EngineReport:
     slot_state_bytes: int = 0          # per-slot non-paged state (hybrid)
     cache_bytes_alloc: int = 0         # full backing allocation
     wall_s: float = 0.0
-    decode_wall_s: float = 0.0         # steady-state only (see below)
-    # first dispatch of each decode jit signature is charged here, not
-    # to decode_wall_s, so wall-clock comparisons measure steady state
-    compile_wall_s: float = 0.0
     # decode-loop host<->device traffic (prefill excluded — identical on
     # every path): decode dispatches + state-sync uploads, host syncs
     # that block on a device result, and page-table bytes shipped
@@ -254,13 +240,9 @@ class EngineReport:
             **{k: round(v, 1)
                for k, v in self.latency_percentiles().items()},
             "wall_s": round(self.wall_s, 3),
-            "decode_wall_s": round(self.decode_wall_s, 4),
-            "compile_wall_s": round(self.compile_wall_s, 4),
             "device_dispatches": self.device_dispatches,
             "host_syncs": self.host_syncs,
             "page_table_upload_bytes": self.page_table_upload_bytes,
-            "tokens_per_s": round(self.new_tokens / self.decode_wall_s, 1)
-            if self.decode_wall_s > 0 else 0.0,
         }
 
 
@@ -275,7 +257,8 @@ class EngineReport:
 #   supports(cfg)     -- classmethod: can this backend serve the config
 #   can_ever_fit(...) -- admission feasibility for this cache shape
 #   admission_rows(pgr, ctx_len) -> table rows the prefill pages fill
-#   prefill(ctx, extras, slot, pages) / decode(...) / release_slot(slot)
+#   prefill(ctx, extras, slot, pages) -> last logits on the host
+#   decode(...) -> logits on the device (the caller syncs) / release_slot(slot)
 
 
 def _bucket_prompt(ctx: np.ndarray, ecfg: EngineConfig, pages: list[int],
@@ -342,12 +325,12 @@ class _PagedBackendBase(_FusedDecode):
     def supports(cls, cfg) -> bool:
         return True
 
-    def decode(self, tokens, page_table, lengths, active) -> np.ndarray:
+    def decode(self, tokens, page_table, lengths, active) -> jax.Array:
         logits, self.state = self._decode(
             self.params, self.state, jnp.asarray(tokens),
             jnp.asarray(page_table), jnp.asarray(lengths),
             jnp.asarray(active))
-        return np.asarray(logits)
+        return logits
 
     def release_slot(self, slot: int) -> None:
         pass                            # pages freed by the allocator
@@ -522,10 +505,10 @@ class RecurrentBackend(_FusedDecode):
                                  jnp.asarray(slot, jnp.int32))
         return np.asarray(logits[0])
 
-    def decode(self, tokens, page_table, lengths, active) -> np.ndarray:
+    def decode(self, tokens, page_table, lengths, active) -> jax.Array:
         logits, self.state = self._decode(self.params, self.state,
                                           jnp.asarray(tokens))
-        return np.asarray(logits)
+        return logits
 
     def release_slot(self, slot: int) -> None:
         pass                            # overwritten at next admission
@@ -834,7 +817,6 @@ class Engine:
         # greedy sampling is pure argmax, so it can run on device inside
         # the fused horizon; the host RNG's temperature draw cannot
         self._fused = self.ecfg.greedy and self.ecfg.horizon > 1
-        self._dispatched: set = set()  # jit signatures already compiled
 
     # -- main loop ---------------------------------------------------------
 
@@ -901,243 +883,269 @@ class Engine:
             sched.requeue(req)
 
         while True:
-            sched.release_arrivals(step)
+            with StepTraceAnnotation("engine.step", step_num=step):
+                sched.release_arrivals(step)
 
-            # -- admission into free slots -------------------------------
-            admitting = True
-            for s in range(B):
-                # retry the same slot until it is filled (rejected or
-                # finished-at-prefill requests must not waste the slot)
-                while admitting and slots[s] is None:
-                    req = sched.peek_ready()
-                    if req is None:
-                        admitting = False
-                        break
-                    ctx = req.context_tokens
-                    assert len(ctx) >= 1, "empty prompts are not admissible"
-                    if paged:
-                        rows = self.backend.admission_rows(pgr, len(ctx))
-                        if not self.backend.can_ever_fit(
-                                pgr, len(req.prompt), req.max_new_tokens,
-                                len(ctx)):
-                            sched.pop_ready()   # can never fit: fail fast
-                            req.truncated = True
-                            req.done_step = step
-                            rep.completed.append(req)
-                            continue
-                        sh_pages, sh_tokens = (
-                            sharer.plan(req, ctx) if sharer is not None
-                            else ([], 0))
-                        need = len(rows) - len(sh_pages)
-                        if not alloc.can_alloc(need) and sharer is not None:
-                            # index-only pages are cache: reclaim them
-                            # before making the request wait
-                            sharer.index.evict_lru(
-                                alloc, need - alloc.free_count,
-                                protect=set(sh_pages))
-                        if not alloc.can_alloc(need):
-                            admitting = False   # FCFS: wait for free pages
+                # -- admission into free slots -------------------------------
+                admitting = True
+                for s in range(B):
+                    # retry the same slot until it is filled (rejected or
+                    # finished-at-prefill requests must not waste the slot)
+                    while admitting and slots[s] is None:
+                        req = sched.peek_ready()
+                        if req is None:
+                            admitting = False
                             break
-                        sched.pop_ready()
-                        if sh_pages:
-                            alloc.share(req.rid, sh_pages)
-                            req.shared_pages += len(sh_pages)
-                            rep.shared_page_hits += len(sh_pages)
-                        pages = alloc.alloc(req.rid, need)
-                        page_table[s, :] = TRASH_PAGE
-                        page_table[s, rows] = sh_pages + pages
-                        if sh_tokens >= len(ctx):
-                            logits = None       # fully cached re-admission
-                        elif sh_tokens:
-                            logits = self.backend.prefill_shared(
-                                ctx, req.extras, s, pages, sh_pages,
-                                sh_tokens)
-                        else:
-                            logits = _routed_prefill(self.backend, req,
-                                                     ctx, s, pages)
-                        full = (-(-len(ctx) // e.prefill_bucket)
-                                * e.prefill_bucket)
-                        computed = 0 if sh_tokens >= len(ctx) else (
-                            -(-(len(ctx) - sh_tokens) // e.prefill_bucket)
-                            * e.prefill_bucket)
-                        rep.prefill_tokens += computed
-                        rep.prefill_tokens_saved += full - computed
-                        if computed:
-                            rep.prefill_calls += 1
-                            req.prefills += 1
-                        if sharer is not None:
-                            sharer.record(alloc, ctx, len(ctx),
-                                          page_table[s])
-                    else:
-                        sched.pop_ready()
-                        logits = _routed_prefill(self.backend, req, ctx,
-                                                 s, None)
-                        rep.prefill_calls += 1
-                        rep.prefill_tokens += len(ctx)
-                        req.prefills += 1
-                    req.admitted_step = step
-                    slots[s] = req
-                    lengths[s] = len(ctx)
-                    if req.generated:   # re-admission after preemption
-                        pending[s] = req.generated[-1]
-                        remaining[s] = (req.max_new_tokens
-                                        - len(req.generated))
-                        ds.touch(s)
-                    else:
-                        assert logits is not None
-                        tok = self._sample(logits)
-                        req.generated.append(tok)
-                        pending[s] = tok
-                        remaining[s] = req.max_new_tokens - 1
-                        ds.touch(s)
-                        if req.done:
-                            finish(s)   # slot freed: while re-admits
+                        with TraceAnnotation("engine.admit", rid=req.rid):
+                            ctx = req.context_tokens
+                            assert len(ctx) >= 1, \
+                                "empty prompts are not admissible"
+                            if paged:
+                                rows = self.backend.admission_rows(
+                                    pgr, len(ctx))
+                                if not self.backend.can_ever_fit(
+                                        pgr, len(req.prompt),
+                                        req.max_new_tokens, len(ctx)):
+                                    # can never fit: fail fast
+                                    sched.pop_ready()
+                                    req.truncated = True
+                                    req.done_step = step
+                                    rep.completed.append(req)
+                                    continue
+                                sh_pages, sh_tokens = (
+                                    sharer.plan(req, ctx)
+                                    if sharer is not None else ([], 0))
+                                need = len(rows) - len(sh_pages)
+                                if not alloc.can_alloc(need) \
+                                        and sharer is not None:
+                                    # index-only pages are cache: reclaim them
+                                    # before making the request wait
+                                    sharer.index.evict_lru(
+                                        alloc, need - alloc.free_count,
+                                        protect=set(sh_pages))
+                                if not alloc.can_alloc(need):
+                                    # FCFS: wait for free pages
+                                    admitting = False
+                                    break
+                                sched.pop_ready()
+                                if sh_pages:
+                                    alloc.share(req.rid, sh_pages)
+                                    req.shared_pages += len(sh_pages)
+                                    rep.shared_page_hits += len(sh_pages)
+                                pages = alloc.alloc(req.rid, need)
+                                page_table[s, :] = TRASH_PAGE
+                                page_table[s, rows] = sh_pages + pages
+                                with TraceAnnotation("engine.prefill"):
+                                    if sh_tokens >= len(ctx):
+                                        # fully cached re-admission
+                                        logits = None
+                                    elif sh_tokens:
+                                        logits = self.backend.prefill_shared(
+                                            ctx, req.extras, s, pages,
+                                            sh_pages, sh_tokens)
+                                    else:
+                                        logits = _routed_prefill(
+                                            self.backend, req, ctx, s, pages)
+                                bucket = e.prefill_bucket
+                                full = -(-len(ctx) // bucket) * bucket
+                                computed = 0 if sh_tokens >= len(ctx) else (
+                                    -(-(len(ctx) - sh_tokens) // bucket)
+                                    * bucket)
+                                rep.prefill_tokens += computed
+                                rep.prefill_tokens_saved += full - computed
+                                if computed:
+                                    rep.prefill_calls += 1
+                                    req.prefills += 1
+                                    tracing.PREFILLS.add(
+                                        len(ctx) - sh_tokens, computed)
+                                if sharer is not None:
+                                    sharer.record(alloc, ctx, len(ctx),
+                                                  page_table[s])
+                            else:
+                                sched.pop_ready()
+                                with TraceAnnotation("engine.prefill"):
+                                    logits = _routed_prefill(
+                                        self.backend, req, ctx, s, None)
+                                rep.prefill_calls += 1
+                                rep.prefill_tokens += len(ctx)
+                                req.prefills += 1
+                                tracing.PREFILLS.add(len(ctx), len(ctx))
+                            req.admitted_step = step
+                            slots[s] = req
+                            lengths[s] = len(ctx)
+                            # re-admission after preemption
+                            if req.generated:
+                                pending[s] = req.generated[-1]
+                                remaining[s] = (req.max_new_tokens
+                                                - len(req.generated))
+                                ds.touch(s)
+                            else:
+                                assert logits is not None
+                                tok = self._sample(logits)
+                                req.generated.append(tok)
+                                pending[s] = tok
+                                remaining[s] = req.max_new_tokens - 1
+                                ds.touch(s)
+                                if req.done:
+                                    finish(s)   # slot freed: while re-admits
 
-            active = [s for s in range(B) if slots[s] is not None]
+                active = [s for s in range(B) if slots[s] is not None]
 
-            # -- page growth / CoW / preemption --------------------------
-            if paged and active:
-                R = self.backend.ring_rows
+                # -- page growth / CoW / preemption --------------------------
+                if paged and active:
+                    with TraceAnnotation("engine.grow"):
+                        R = self.backend.ring_rows
 
-                def claim_one(s: int) -> bool:
-                    """Free one page for slot ``s``: index cache first,
-                    then victim preemption (whose pages may land in the
-                    index — evictable next iteration, so the loop still
-                    strictly shrinks live state). False if ``s`` itself
-                    was preempted."""
-                    while not alloc.can_alloc(1):
-                        if sharer is not None \
-                                and sharer.index.evict_lru(alloc, 1):
-                            continue
-                        victim = Scheduler.pick_victim(
-                            [(v, slots[v]) for v in active
-                             if slots[v] is not None], exclude=s)
-                        if victim is None or victim[0] == s:
-                            preempt(s)
-                            active.remove(s)
-                            return False
-                        preempt(victim[0])
-                        active.remove(victim[0])
-                    return True
+                        def claim_one(s: int) -> bool:
+                            """Free one page for slot ``s``: index cache
+                            first, then victim preemption (whose pages may
+                            land in the index — evictable next iteration, so
+                            the loop still strictly shrinks live state).
+                            False if ``s`` itself was preempted."""
+                            while not alloc.can_alloc(1):
+                                if sharer is not None \
+                                        and sharer.index.evict_lru(alloc, 1):
+                                    continue
+                                victim = Scheduler.pick_victim(
+                                    [(v, slots[v]) for v in active
+                                     if slots[v] is not None], exclude=s)
+                                if victim is None or victim[0] == s:
+                                    preempt(s)
+                                    active.remove(s)
+                                    return False
+                                preempt(victim[0])
+                                active.remove(victim[0])
+                            return True
 
-                for s in list(active):
-                    if slots[s] is None:
-                        continue
-                    if lengths[s] % page != 0:
-                        # mid-page: the next decode appends into the
-                        # current tail page — if that page is still
-                        # shared (re-admission mapped a cached tail),
-                        # copy-on-write exactly that page first
-                        if sharer is None:
-                            continue
-                        row_i = lengths[s] // page
-                        old = int(page_table[s, row_i])
-                        if alloc.refcount(old) < 2:
-                            continue
-                        if not claim_one(s):
-                            continue
-                        new = alloc.alloc(slots[s].rid, 1)
-                        self.backend.copy_page(old, new[0])
-                        alloc.free_page(slots[s].rid, old)
-                        page_table[s, row_i] = new[0]
-                        ds.touch(s)
-                        slots[s].cow_copies += 1
-                        rep.cow_copies += 1
-                        continue
-                    pi = lengths[s] // page
-                    if R is None and pi >= M:   # table row full: stop
-                        slots[s].truncated = True
-                        finish(s)
-                        active.remove(s)
-                        continue
-                    row = _growth_row(self.backend, alloc, page_table, s,
-                                      pi, slots[s].rid)
-                    if not claim_one(s):
-                        continue
-                    new = alloc.alloc(slots[s].rid, 1)
-                    page_table[s, row] = new[0]
-                    ds.touch(s)
+                        for s in list(active):
+                            if slots[s] is None:
+                                continue
+                            if lengths[s] % page != 0:
+                                # mid-page: the next decode appends into the
+                                # current tail page — if that page is still
+                                # shared (re-admission mapped a cached tail),
+                                # copy-on-write exactly that page first
+                                if sharer is None:
+                                    continue
+                                row_i = lengths[s] // page
+                                old = int(page_table[s, row_i])
+                                if alloc.refcount(old) < 2:
+                                    continue
+                                if not claim_one(s):
+                                    continue
+                                new = alloc.alloc(slots[s].rid, 1)
+                                self.backend.copy_page(old, new[0])
+                                alloc.free_page(slots[s].rid, old)
+                                page_table[s, row_i] = new[0]
+                                ds.touch(s)
+                                slots[s].cow_copies += 1
+                                rep.cow_copies += 1
+                                continue
+                            pi = lengths[s] // page
+                            if R is None and pi >= M:   # table row full: stop
+                                slots[s].truncated = True
+                                finish(s)
+                                active.remove(s)
+                                continue
+                            row = _growth_row(self.backend, alloc,
+                                              page_table, s, pi, slots[s].rid)
+                            if not claim_one(s):
+                                continue
+                            new = alloc.alloc(slots[s].rid, 1)
+                            page_table[s, row] = new[0]
+                            ds.touch(s)
 
-            # -- decode: one fused horizon, or one per-step dispatch -----
-            if active:
-                act = np.zeros((B,), bool)
-                act[active] = True
-                if self._fused:
-                    # safe horizon: no schedulable event may land inside
-                    # it, so running h steps device-side is step-for-step
-                    # identical to h per-step iterations of this loop
-                    h = e.horizon
-                    nxt = sched.next_arrival()
-                    if nxt is not None:
-                        h = min(h, nxt - step)     # arrival -> admission
-                    if sched.peek_ready() is not None and \
-                            any(slots[s] is None for s in range(B)):
-                        h = 1   # a free slot retries admission per step
-                    for s in active:
-                        h = min(h, int(remaining[s]))  # finish at bound
+                # -- decode: one fused horizon, or one per-step dispatch -----
+                if active:
+                    act = np.zeros((B,), bool)
+                    act[active] = True
+                    if self._fused:
+                        # safe horizon: no schedulable event may land inside
+                        # it, so running h steps device-side is step-for-step
+                        # identical to h per-step iterations of this loop;
+                        # ``cause`` names the cut that bound h (a later cut
+                        # takes it over only by cutting shorter)
+                        h, cause = e.horizon, "cap"
+                        nxt = sched.next_arrival()
+                        if nxt is not None and nxt - step < h:
+                            h, cause = nxt - step, "arrival"
+                        if sched.peek_ready() is not None and \
+                                any(slots[s] is None for s in range(B)):
+                            # a free slot retries admission per step
+                            h, cause = 1, "admit"
+                        fin = min(int(remaining[s]) for s in active)
+                        if fin < h:
+                            h, cause = fin, "finish"
                         if paged:                      # growth/ring wrap
-                            h = min(h, pgr.steps_to_boundary(
-                                int(lengths[s])))
-                    h = max(1, h)
-                    ds.sync(page_table, lengths, pending, remaining)
-                    t0 = time.monotonic()
-                    out, p_d, l_d, r_d = self.backend.decode_fused(
-                        ds.pending, ds.lengths, ds.remaining, ds.table,
-                        act, h)
-                    toks_h = np.asarray(out)   # ONE host sync per horizon
-                    _charge_wall(rep, self._dispatched, "fused",
-                                 time.monotonic() - t0)
-                    ds.adopt(p_d, l_d, r_d)
-                    ds.count(dispatches=1, syncs=1)
-                    rep.decode_steps += h
-                    rep.slot_steps += B * h
-                    rep.useful_slot_steps += len(active) * h
-                    step += h - 1   # bookkeeping lands at horizon end
-                    lengths[active] += h
-                    remaining[active] -= h
-                    for s in active:
-                        req = slots[s]
-                        req.generated.extend(int(t) for t in toks_h[:h, s])
-                        pending[s] = int(toks_h[h - 1, s])
-                        if req.done:
-                            finish(s)
+                            edge = min(pgr.steps_to_boundary(int(lengths[s]))
+                                       for s in active)
+                            if edge < h:
+                                h, cause = edge, "page"
+                        h = max(1, h)
+                        with TraceAnnotation("engine.sync"):
+                            ds.sync(page_table, lengths, pending, remaining)
+                        tracing.DISPATCHES.add(h, len(active), cause)
+                        with TraceAnnotation("engine.decode"):
+                            out, p_d, l_d, r_d = self.backend.decode_fused(
+                                ds.pending, ds.lengths, ds.remaining,
+                                ds.table, act, h)
+                        with TraceAnnotation("engine.wait"):
+                            toks_h = np.asarray(out)   # ONE host sync
+                        ds.adopt(p_d, l_d, r_d)
+                        ds.count(dispatches=1, syncs=1)
+                        rep.decode_steps += h
+                        rep.slot_steps += B * h
+                        rep.useful_slot_steps += len(active) * h
+                        step += h - 1   # bookkeeping lands at horizon end
+                        lengths[active] += h
+                        remaining[active] -= h
+                        with TraceAnnotation("engine.deliver"):
+                            for s in active:
+                                req = slots[s]
+                                req.generated.extend(
+                                    int(t) for t in toks_h[:h, s])
+                                pending[s] = int(toks_h[h - 1, s])
+                                if req.done:
+                                    finish(s)
+                    else:
+                        tracing.DISPATCHES.add(1, len(active), "unfused")
+                        with TraceAnnotation("engine.decode"):
+                            logits = self.backend.decode(
+                                pending, page_table, lengths, act)
+                        with TraceAnnotation("engine.wait"):
+                            logits = np.asarray(logits)
+                        ds.count(dispatches=1, syncs=1,
+                                 upload_bytes=page_table.nbytes)
+                        rep.decode_steps += 1
+                        rep.slot_steps += B    # the batch always runs full
+                        rep.useful_slot_steps += len(active)
+                        lengths[active] += 1
+                        remaining[active] -= 1
+                        with TraceAnnotation("engine.deliver"):
+                            toks = self._sample_batch(logits[active])
+                            for i, s in enumerate(active):
+                                req = slots[s]
+                                tok = int(toks[i])
+                                req.generated.append(tok)
+                                pending[s] = tok
+                                if req.done:
+                                    finish(s)
+                    if paged:
+                        rep.peak_live_pages = max(rep.peak_live_pages,
+                                                  alloc.live_count)
+                        rep.peak_demand_pages = max(rep.peak_demand_pages,
+                                                    alloc.demand_count)
+                elif not sched.exhausted:
+                    nxt = sched.next_arrival()
+                    if nxt is not None and nxt > step:
+                        step = nxt      # idle: fast-forward to next arrival
+                        continue
                 else:
-                    t0 = time.monotonic()
-                    logits = self.backend.decode(pending, page_table,
-                                                 lengths, act)
-                    _charge_wall(rep, self._dispatched, "decode",
-                                 time.monotonic() - t0)
-                    ds.count(dispatches=1, syncs=1,
-                             upload_bytes=page_table.nbytes)
-                    rep.decode_steps += 1
-                    rep.slot_steps += B    # the batch always runs full
-                    rep.useful_slot_steps += len(active)
-                    lengths[active] += 1
-                    remaining[active] -= 1
-                    toks = self._sample_batch(logits[active])
-                    for i, s in enumerate(active):
-                        req = slots[s]
-                        tok = int(toks[i])
-                        req.generated.append(tok)
-                        pending[s] = tok
-                        if req.done:
-                            finish(s)
-                if paged:
-                    rep.peak_live_pages = max(rep.peak_live_pages,
-                                              alloc.live_count)
-                    rep.peak_demand_pages = max(rep.peak_demand_pages,
-                                                alloc.demand_count)
-            elif not sched.exhausted:
-                nxt = sched.next_arrival()
-                if nxt is not None and nxt > step:
-                    step = nxt          # idle: fast-forward to next arrival
-                    continue
-            else:
-                break
+                    break
 
-            step += 1
-            if step > e.max_steps:
-                raise RuntimeError("engine exceeded max_steps")
+                step += 1
+                if step > e.max_steps:
+                    raise RuntimeError("engine exceeded max_steps")
 
         if paged:
             if sharer is not None:      # drop the index's neutral refs
@@ -1396,7 +1404,6 @@ class PooledEngine:
         self._sample = make_sampler(self.rng, self.ecfg.greedy,
                                     self.ecfg.temperature)
         self._fused = self.ecfg.greedy and self.ecfg.horizon > 1
-        self._dispatched: set = set()  # jit signatures already compiled
 
     # -- main loop ---------------------------------------------------------
     # The loop is split into start / step_once / finish_run so a caller
@@ -1893,13 +1900,10 @@ class PooledEngine:
                     # other tenants' table rows on device) and donates
                     # the loop arrays to the next tenant's call
                     ds = self._ds
-                    t0 = time.monotonic()
                     out, p_d, l_d, r_d = backend.decode_fused(
                         ds.pending, ds.lengths, ds.remaining, ds.table,
                         act, h)
                     toks_h = np.asarray(out)   # one host sync/tenant
-                    _charge_wall(rep, self._dispatched, ("fused", m),
-                                 time.monotonic() - t0)
                     ds.adopt(p_d, l_d, r_d)
                     ds.count(dispatches=1, syncs=1)
                     lengths[m_slots] += h
@@ -1920,10 +1924,8 @@ class PooledEngine:
                     # its pool
                     pt_m = np.where(act[:, None], page_table, TRASH_PAGE)
                     len_m = np.where(act, lengths, 0).astype(np.int32)
-                    t0 = time.monotonic()
-                    logits = backend.decode(toks, pt_m, len_m, act)
-                    _charge_wall(rep, self._dispatched, ("decode", m),
-                                 time.monotonic() - t0)
+                    logits = np.asarray(backend.decode(toks, pt_m, len_m,
+                                                       act))
                     self._ds.count(dispatches=1, syncs=1,
                                    upload_bytes=page_table.nbytes)
                     lengths[m_slots] += 1
@@ -2066,7 +2068,6 @@ def run_static(cfg, params, requests: list[Request], *, num_slots: int = 8,
                          donate_argnums=(1,))
     sample_batch = make_batch_sampler(np.random.default_rng(seed), greedy,
                                       temperature)
-    dispatched: set = set()            # decode signatures already traced
 
     t_run = time.monotonic()
     step = 0
@@ -2102,12 +2103,8 @@ def run_static(cfg, params, requests: list[Request], *, num_slots: int = 8,
         for _ in range(gen - 1):        # lockstep drain to the longest
             tok = jnp.asarray(np.asarray(
                 [r.generated[-1] for r in group], np.int32))
-            t0 = time.monotonic()
             logits, state = decode_jit(params, state, tok)
             logits = np.asarray(logits)
-            _charge_wall(rep, dispatched,
-                         ("static", cache_len, len(group)),
-                         time.monotonic() - t0)
             rep.decode_steps += 1
             rep.slot_steps += len(group)
             step += 1

@@ -406,9 +406,7 @@ def test_pooled_engine_deterministic_replay():
                                 greedy=False, temperature=0.8, seed=3)
         rep = PooledEngine(pool, params, ecfg).run(copy.deepcopy(trace))
         s = rep.summary()
-        for k in ("wall_s", "tokens_per_s", "decode_wall_s",
-                  "compile_wall_s", "wall_tokens_per_s"):
-            s.pop(k, None)
+        s.pop("wall_s")                     # timing, not behaviour
         return s, {r.rid: r.generated for r in rep.completed}
 
     assert go() == go()

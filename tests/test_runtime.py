@@ -227,9 +227,7 @@ def test_engine_deterministic_replay_with_preemptions():
     def go():
         rep = Engine(cfg, params, tiny).run(copy.deepcopy(trace))
         s = rep.summary()
-        for k in ("wall_s", "tokens_per_s", "decode_wall_s",
-                  "compile_wall_s"):                # timing, not behaviour
-            s.pop(k, None)
+        s.pop("wall_s")                     # timing, not behaviour
         return rep, s
 
     rep1, s1 = go()
