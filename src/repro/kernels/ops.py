@@ -88,10 +88,13 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     """Paged decode attention: q (B, H, dh) model layout; k/v_pages
     (KV, P, page, dh) *kernel* layout (models.layers.paged_cache_init
     stores pools head-major precisely so the decode hot loop pays no
-    pool-wide relayout here); page_table (B, M) int32; lengths (B,)."""
+    pool-wide relayout here); page_table (B, M) int32; lengths (B,).
+    On TPU the kernel needs dh a multiple of 128 (it copies pages out of
+    HBM, and Mosaic cannot slice a row narrower than a lane tile there);
+    narrower heads, which no served configuration has, take the reference."""
     B, H, dh = q.shape
     KV = k_pages.shape[0]
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    if impl == "ref" or (impl == "auto" and (not _on_tpu() or dh % 128)):
         kt = jnp.transpose(k_pages, (1, 2, 0, 3))  # (P, page, KV, dh)
         vt = jnp.transpose(v_pages, (1, 2, 0, 3))
         return ref.paged_decode_attention(q, kt, vt, page_table, lengths,

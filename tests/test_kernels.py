@@ -4,6 +4,8 @@ Sweeps shapes/dtypes per the task spec. bf16 tolerances are loose (the
 kernels accumulate in f32 but inputs are quantized to bf16).
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,9 @@ from repro.kernels import (build_block_meta, decode_attention,
                            flash_attention, grouped_mvm,
                            packed_canvas_matmul, ref)
 from repro.kernels import ops
+
+# the module; the package re-exports its function under the same name
+_dec = importlib.import_module("repro.kernels.decode_attention")
 
 # f32 tol covers blocked-reduction order differences vs one-shot einsum
 TOL = {jnp.float32: dict(rtol=1e-4, atol=2e-4),
@@ -212,13 +217,17 @@ def test_ops_moe_ffn():
 
 # --- paged decode attention ----------------------------------------------------------
 
-def _paged_case(key, B, KV, dh, P, page, M, lens, dtype=jnp.float32):
-    """k/v pools in kernel layout (KV, P, page, dh) + table + lengths."""
+def _paged_case(key, B, KV, dh, P, page, M, lens, dtype=jnp.float32,
+                shuffled=False):
+    """k/v pools in kernel layout (KV, P, page, dh) + table + lengths; pages
+    owned in id order, or (``shuffled``) out of order and non-contiguous."""
     ks = jax.random.split(key, 3)
     kp = rand(ks[0], (KV, P, page, dh), dtype)
     vp = rand(ks[1], (KV, P, page, dh), dtype)
     pt = np.zeros((B, M), np.int32)
-    free = iter(range(1, P))
+    ids = np.arange(1, P)
+    free = iter(np.random.default_rng(0).permutation(ids) if shuffled
+                else ids)
     for b in range(B):
         for i in range(-(-int(lens[b]) // page)):
             pt[b, i] = next(free)
@@ -233,6 +242,17 @@ def _to_model_layout(pages):
     (4, 2, 4, 16, 12, 8, 4, [5, 8, 17, 0]),       # partial/full/multi/empty
     (2, 4, 1, 32, 6, 16, 2, [16, 31]),
     (3, 1, 6, 64, 16, 128, 4, [1, 512, 129]),     # MHA-style big pages
+    # block geometry (decode_attention._paged_geometry): page 16 gives
+    # ppb = 8 pages per compute block, page 8 gives 16
+    pytest.param(2, 2, 2, 32, 30, 16, 16, [200, 77], id="ends-mid-block"),
+    pytest.param(2, 2, 2, 32, 20, 16, 12, [128, 144],
+                 id="one-block-and-one-page-past"),
+    pytest.param(3, 1, 4, 32, 40, 16, 20, [320, 250, 16],
+                 id="table-not-multiple-of-block"),
+    pytest.param(2, 4, 1, 32, 24, 16, 10, [150, 40],
+                 id="mha-several-heads-per-block"),
+    pytest.param(2, 16, 1, 128, 12, 16, 4, [40, 17],
+                 id="two-head-blocks"),           # kvb = 8 of 16 heads
 ])
 def test_paged_decode_attention_oracle(B, KV, G, dh, P, page, M, lens):
     H = KV * G
@@ -245,6 +265,18 @@ def test_paged_decode_attention_oracle(B, KV, G, dh, P, page, M, lens):
                                       _to_model_layout(vp), pt, lengths)
     # acceptance bar: paged kernel matches the jnp oracle to <= 1e-5
     assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 1e-5
+
+
+@pytest.mark.parametrize("KV,page,dh,M,itemsize,want", [
+    (16, 16, 128, 129, 2, (16, 8)),     # olmo-1b.chat: 64 KiB per page DMA
+    (4, 16, 128, 129, 2, (4, 8)),       # GQA with 4 KV heads
+    (16, 16, 128, 4, 4, (8, 4)),        # float32: two head blocks; M caps ppb
+    (1, 128, 256, 4, 4, (1, 1)),        # a head's page over 64 KiB
+])
+def test_paged_geometry(KV, page, dh, M, itemsize, want):
+    """kvb divides KV with a page DMA of at most 64 KiB; ppb gives 128 rows
+    per head and block, at least one page and at most the table."""
+    assert _dec._paged_geometry(KV, page, dh, M, itemsize) == want
 
 
 def test_paged_matches_dense_decode_attention():
@@ -264,26 +296,40 @@ def test_paged_matches_dense_decode_attention():
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("B,KV,G,dh,P,page,M,lens", [
+@pytest.mark.parametrize("B,KV,G,dh,P,page,M,lens,shuffled", [
     # context lengths exactly at page boundaries (incl. a full table row)
-    (3, 2, 2, 16, 14, 8, 4, [8, 16, 32]),
+    pytest.param(3, 2, 2, 16, 14, 8, 4, [8, 16, 32], False,
+                 id="3-2-2-16-14-8-4-lens0"),
     # single-token contexts (first page barely occupied)
-    (3, 2, 2, 16, 6, 8, 4, [1, 1, 1]),
+    pytest.param(3, 2, 2, 16, 6, 8, 4, [1, 1, 1], False,
+                 id="3-2-2-16-6-8-4-lens1"),
     # all slots dead: no valid keys anywhere, output must be exactly zero
-    (4, 2, 2, 16, 5, 8, 4, [0, 0, 0, 0]),
+    pytest.param(4, 2, 2, 16, 5, 8, 4, [0, 0, 0, 0], False,
+                 id="4-2-2-16-5-8-4-lens2"),
     # non-power-of-two page-table geometry (M=3, P=7) and page size 12
-    (2, 2, 2, 16, 7, 12, 3, [13, 30]),
+    pytest.param(2, 2, 2, 16, 7, 12, 3, [13, 30], False,
+                 id="2-2-2-16-7-12-3-lens3"),
     # mixed: boundary + dead + single in one batch, odd table width
-    (5, 1, 4, 32, 16, 8, 5, [24, 0, 1, 33, 40]),
+    pytest.param(5, 1, 4, 32, 16, 8, 5, [24, 0, 1, 33, 40], False,
+                 id="5-1-4-32-16-8-5-lens4"),
+    # page ids owned out of order and non-contiguous, across two blocks
+    pytest.param(3, 2, 2, 16, 40, 8, 20, [130, 70, 9], True,
+                 id="shuffled-pages"),
+    # dead, single-token and full-table slots in one batch (page 8: 16
+    # pages per block, so the full table ends 12 columns into its 2nd)
+    pytest.param(4, 2, 2, 16, 40, 8, 20, [0, 1, 160, 0], True,
+                 id="dead-single-full-table"),
 ])
-def test_paged_decode_attention_edge_shapes(B, KV, G, dh, P, page, M, lens):
+def test_paged_decode_attention_edge_shapes(B, KV, G, dh, P, page, M, lens,
+                                            shuffled):
     """Differential check at the shapes the engine actually produces:
-    page-boundary lengths, single-token contexts, fully dead batches, and
-    non-power-of-two table geometry must all match the jnp oracle."""
+    page-boundary lengths, single-token contexts, fully dead batches,
+    non-power-of-two table geometry and pages owned out of order must all
+    match the jnp oracle."""
     H = KV * G
     q = rand(jax.random.PRNGKey(6), (B, H, dh), jnp.float32)
     kp, vp, pt, lengths = _paged_case(jax.random.PRNGKey(7), B, KV, dh, P,
-                                      page, M, lens)
+                                      page, M, lens, shuffled=shuffled)
     got = ops.paged_decode_attention(q, kp, vp, pt, lengths,
                                      impl="interpret")
     want = ref.paged_decode_attention(q, _to_model_layout(kp),

@@ -11,6 +11,8 @@ so only the worker that runs this file loads the TPU compiler library.
 """
 
 import contextlib
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -69,17 +71,53 @@ def _assert_kernel_compiles(fn, sharding, *shapes):
 BF16, I32 = jnp.bfloat16, jnp.int32
 
 
-@pytest.mark.parametrize("batch,page,table_cols", [
-    (8, 8, 32), (8, 16, 32), (8, 32, 32), (64, 16, 256)])
-def test_paged_decode_attention_compiles(one_chip, batch, page, table_cols):
+def _paged_shapes(batch, page, table_cols, pages):
     kv, dh = OLMO.num_kv_heads, OLMO.head_dim
     g = OLMO.num_heads // kv
-    pages = 1 + batch * table_cols
+    return (((batch, kv, g, dh), BF16), ((kv, pages, page, dh), BF16),
+            ((kv, pages, page, dh), BF16), ((batch, table_cols), I32),
+            ((batch,), I32))
+
+
+# olmo-1b.chat in the chip benchmark: 20 slots, 1,766 pages of 16, 129 columns
+BENCH_CELL = (20, 16, 129, 1766)
+
+
+@pytest.mark.parametrize("batch,page,table_cols,pages", [
+    pytest.param(8, 8, 32, None, id="8-8-32"),
+    pytest.param(8, 16, 32, None, id="8-16-32"),
+    pytest.param(8, 32, 32, None, id="8-32-32"),
+    pytest.param(64, 16, 256, None, id="64-16-256"),
+    pytest.param(*BENCH_CELL, id="olmo-1b.chat"),
+])
+def test_paged_decode_attention_compiles(one_chip, batch, page, table_cols,
+                                         pages):
     _assert_kernel_compiles(
         paged_decode_attention, one_chip,
-        ((batch, kv, g, dh), BF16), ((kv, pages, page, dh), BF16),
-        ((kv, pages, page, dh), BF16), ((batch, table_cols), I32),
-        ((batch,), I32))
+        *_paged_shapes(batch, page, table_cols,
+                       pages or 1 + batch * table_cols))
+
+
+def test_paged_decode_attention_keeps_benchmark_name(one_chip, monkeypatch):
+    """The benchmark finds the kernel in the device trace by the HLO name of
+    its custom call, which comes from the jitted wrapper
+    (``chipbench/metrics/paged_attn_roofline.KERNEL``). Called inside a
+    larger program, as the decode step calls it, the compiled HLO must still
+    hold an instruction of that name."""
+    monkeypatch.syspath_prepend(str(Path(__file__).resolve().parents[1]))
+    from chipbench.metrics.paged_attn_roofline import KERNEL
+
+    def step(q, k_pages, v_pages, page_table, lengths):
+        return paged_decode_attention(q * 2, k_pages, v_pages, page_table,
+                                      lengths) + 1
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in _paged_shapes(*BENCH_CELL)]
+    with _no_persistent_cache():
+        text = jax.jit(step).lower(*args).compile().as_text()
+    names = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines()]
+    assert any(re.search(KERNEL, n) for n in names), KERNEL
 
 
 @pytest.mark.parametrize("cfg", [OLMO, QWEN2_VL], ids=lambda c: c.name)
