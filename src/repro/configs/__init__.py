@@ -1,7 +1,7 @@
 """Architecture registry: ``get_config(arch_id)`` for every assigned arch."""
 
 from .base import (SHAPES, EncoderConfig, InputShape, MLAConfig, MoEConfig,
-                   ModelConfig, RecurrentConfig)
+                   ModelConfig, RecurrentConfig, RopeScaling)
 from . import (codeqwen15_7b, command_r_35b, command_r_plus_104b,
                deepseek_v2_lite_16b, olmo_1b, olmoe_1b_7b, qwen2_vl_7b,
                recurrentgemma_9b, rwkv6_7b, whisper_tiny)
@@ -33,4 +33,4 @@ def shapes_for(arch_id: str) -> tuple[str, ...]:
 
 __all__ = ["REGISTRY", "ARCH_IDS", "get_config", "shapes_for", "SHAPES",
            "ModelConfig", "MoEConfig", "MLAConfig", "RecurrentConfig",
-           "EncoderConfig", "InputShape"]
+           "RopeScaling", "EncoderConfig", "InputShape"]

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from collections.abc import Mapping
 from typing import Literal
 
 Family = Literal["dense", "moe", "ssm", "hybrid", "encdec", "vlm"]
@@ -17,11 +18,20 @@ Family = Literal["dense", "moe", "ssm", "hybrid", "encdec", "vlm"]
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts of one MoE layer. ``num_experts`` is how many this
+    chip holds, ids ``0 .. num_experts - 1`` of the router's
+    ``router_experts`` (0: the router's width is ``num_experts``, every
+    expert is held). Claims on experts held elsewhere are left out."""
     num_experts: int
     top_k: int
     d_ff_expert: int
     num_shared_experts: int = 0
     capacity_factor: float = 1.25
+    router_experts: int = 0
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,6 +41,22 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling, with the keys of DeepSeek-V2's ``rope_scaling``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    type: str = "yarn"
+
+    def __post_init__(self):
+        if self.type != "yarn":
+            raise ValueError(f"rope scaling {self.type!r}: only YaRN runs")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,12 +92,27 @@ class ModelConfig:
     tie_embeddings: bool = False
     rope_theta: float = 10000.0
     mrope: bool = False            # Qwen2-VL multimodal 3-D RoPE
+    rope_scaling: RopeScaling | None = None
+    first_k_dense: int = 0         # leading layers with a dense FFN of d_ff
     moe: MoEConfig | None = None
     mla: MLAConfig | None = None
     recurrent: RecurrentConfig | None = None
     encoder: EncoderConfig | None = None
 
     def __post_init__(self):
+        # nested groups may come as mappings (a JSON configuration file)
+        for name, kind in (("rope_scaling", RopeScaling), ("moe", MoEConfig),
+                           ("mla", MLAConfig), ("recurrent", RecurrentConfig),
+                           ("encoder", EncoderConfig)):
+            v = getattr(self, name)
+            if isinstance(v, Mapping):
+                v = dict(v)
+                if "block_pattern" in v:
+                    v["block_pattern"] = tuple(v["block_pattern"])
+                object.__setattr__(self, name, kind(**v))
+        if not 0 <= self.first_k_dense <= self.num_layers:
+            raise ValueError(f"first_k_dense {self.first_k_dense} of "
+                             f"{self.num_layers} layers")
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.num_heads)
@@ -109,7 +150,7 @@ class ModelConfig:
         if self.moe:
             fe = self.moe.d_ff_expert
             ffn = (self.moe.num_experts + self.moe.num_shared_experts) \
-                * 3 * D * fe + D * self.moe.num_experts
+                * 3 * D * fe + D * self.moe.router_width
         if self.family == "hybrid" and self.recurrent:
             W = self.recurrent.lru_width or D
             rec = D * W * 2 + W * D + W * self.recurrent.conv_width + 2 * W
@@ -118,6 +159,8 @@ class ModelConfig:
                            i % len(self.recurrent.block_pattern)] == "attn")
             att = att * natt / L + rec * (1 - natt / L)  # averaged per block
         blocks = L * (att + ffn + 2 * D)
+        if self.first_k_dense:      # leading dense layers: SwiGLU of d_ff
+            blocks += self.first_k_dense * (3 * D * F - ffn)
         if self.encoder and self.family == "encdec":
             enc = self.encoder.num_layers * (4 * D * D + 2 * D * F + 2 * D)
             blocks += enc + L * (4 * D * D)  # cross-attention
@@ -127,7 +170,7 @@ class ModelConfig:
         """Parameters touched per token (MoE: only routed top-k + shared)."""
         if not self.moe:
             return self.param_count()
-        D, L = self.d_model, self.num_layers
+        D, L = self.d_model, self.num_layers - self.first_k_dense
         fe = self.moe.d_ff_expert
         total = self.param_count()
         all_experts = L * self.moe.num_experts * 3 * D * fe
@@ -139,10 +182,16 @@ class ModelConfig:
 
         Hybrid archs keep one full (rec, rec, attn) pattern period so the
         windowed-attention path (and its serving cache) is exercised —
-        two layers would reduce to a pure-recurrence stack."""
+        two layers would reduce to a pure-recurrence stack. MoE archs
+        keep one leading dense layer if they have any (then one MoE
+        layer follows it), YaRN if they use it, a shared expert if they
+        have one, and hold half of a router of 8 experts, so the expert
+        share is exercised."""
         kw: dict = dict(
             name=self.name + "-smoke", family=self.family,
-            num_layers=3 if self.family == "hybrid" else 2, d_model=64,
+            num_layers=3 if self.family == "hybrid" else 2,
+            first_k_dense=min(self.first_k_dense, 1),
+            rope_scaling=self.rope_scaling, d_model=64,
             num_heads=4, num_kv_heads=max(1, min(self.num_kv_heads, 2)),
             head_dim=16, d_ff=128, vocab_size=256, norm=self.norm,
             use_bias=self.use_bias, qkv_bias=self.qkv_bias,
@@ -150,13 +199,13 @@ class ModelConfig:
             mrope=self.mrope)
         if self.moe:
             # the arch's own capacity_factor: drops CAN occur at smoke
-            # sizes, and the serving paths stay consistent anyway — the
-            # engine keys the exact-length capacity into the jit cache
-            # (prefill) and decodes dropless (layers.moe_dims_dropless).
+            # sizes in training; serving routes dropless
+            # (layers.moe_dims_dropless)
             kw["moe"] = MoEConfig(num_experts=4, top_k=2, d_ff_expert=32,
                                   num_shared_experts=self.moe.num_shared_experts
                                   and 1,
-                                  capacity_factor=self.moe.capacity_factor)
+                                  capacity_factor=self.moe.capacity_factor,
+                                  router_experts=8)
         if self.mla:
             kw["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
                                   qk_rope_head_dim=8, v_head_dim=16)

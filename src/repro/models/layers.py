@@ -194,18 +194,50 @@ def make_norm(cfg):
 
 # --- rotary embeddings ----------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (DeepSeek-V2 ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_range(scaling, head_dim: int, theta: float) -> tuple[int, int]:
+    """The rotary pairs YaRN ramps between extrapolation (below ``low``)
+    and interpolation by ``factor`` (above ``high``): the pairs that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context."""
+    def dim(rotations):
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(dim(scaling.beta_fast)), 0),
+            min(math.ceil(dim(scaling.beta_slow)), head_dim - 1))
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None):
+    """Inverse frequencies of the ``head_dim // 2`` rotary pairs; with a
+    YaRN ``scaling`` (configs.RopeScaling), blended between theta's and
+    theta's over ``factor`` by a linear ramp over ``yarn_range``."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if scaling is None:
+        return freqs
+    lo, hi = yarn_range(scaling, head_dim, theta)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo)
+                    / (hi - lo if hi > lo else 0.001), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x, positions, theta):
-    """x: (B, S, H, dh); positions: (B, S) int32."""
+def apply_rope(x, positions, theta, scaling=None):
+    """x: (B, S, H, dh); positions: (B, S) int32. ``scaling`` is a
+    configs.RopeScaling (YaRN) or None for plain RoPE."""
     half = x.shape[-1] // 2
-    freqs = rope_freqs(x.shape[-1], theta)                    # (half,)
+    freqs = rope_freqs(x.shape[-1], theta, scaling)           # (half,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B,S,half)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -375,21 +407,22 @@ def gelu_mlp(x, w_up, b_up, w_down, b_down):
 
 @dataclasses.dataclass(frozen=True)
 class MoEDims:
+    """``num_experts`` experts held here, router ids ``first ..
+    first + num_experts - 1``, each taking at most ``capacity`` claims."""
     num_experts: int
     top_k: int
     capacity: int
+    first: int = 0
 
 
 def moe_dims(cfg, n_tokens: int) -> MoEDims:
-    """Expert-capacity ceiling for routing ``n_tokens`` tokens.
-
-    ``n_tokens`` must be the EXACT live token count, not a padded shape:
-    the ceiling is shape-static, so computing it from a padded bucket
-    inflates capacity and keeps tokens the exact-length oracle would
-    drop. Serving paths key the exact-length CAPACITY into the jit cache
-    as a static argument (moe.forward's ``route_capacity``)."""
+    """Expert-capacity ceiling for routing ``n_tokens`` tokens in training
+    (GShard's capacity factor; overflowing claims drop). Serving routes
+    dropless (``moe_dims_dropless``)."""
     m = cfg.moe
-    cap = int(math.ceil(n_tokens / m.num_experts * m.capacity_factor
+    # an expert's share of the claims is of the router's width, however
+    # many of its experts are held here
+    cap = int(math.ceil(n_tokens / m.router_width * m.capacity_factor
                         * m.top_k))
     cap = max(cap, 4)
     # align capacity to the MXU lane quantum: this is the IMC-paper's
@@ -399,89 +432,65 @@ def moe_dims(cfg, n_tokens: int) -> MoEDims:
 
 
 def moe_dims_dropless(cfg, n_tokens: int) -> MoEDims:
-    """Decode-step dims whose capacity no routing pattern can overflow
-    (every expert can absorb all ``n_tokens``). A decode batch holds one
-    token from each of ``n_tokens`` INDEPENDENT requests; the B=1 oracle
-    never drops at decode (a lone token's expert-queue position is 0),
-    so batching decode tokens must not introduce cross-request drops —
-    a slot's output may never depend on which neighbours share its step."""
+    """Dims whose capacity no routing pattern can overflow: every expert
+    can absorb all ``n_tokens`` (a token claims an expert at most once).
+    Serving routes so at prefill and at decode, as DeepSeek-V2's released
+    inference code does: a token's output never depends on which other
+    tokens share its batch, its bucket padding, or its step."""
     m = cfg.moe
     return MoEDims(m.num_experts, m.top_k, max(n_tokens, 4))
 
 
-def moe_router(x2d, w_router, dims: MoEDims, *, keep_override=None,
-               return_keep=False):
-    """Top-k softmax routing with capacity. x2d: (N, D) -> dispatch (N, E, C)
-    one-hot and combine (N, E, C) weights; overflowed tokens drop (standard
-    GShard behaviour).
-
-    ``keep_override`` ((N, k) bool) REPLAYS a recorded drop population:
-    claims forced False never enter an expert queue, claims forced True
-    take queue positions counted over the forced-keep claims only — so a
-    re-prefill after preemption reproduces the original routing exactly
-    (capacity permitting). ``return_keep`` appends the realized (N, k)
-    keep mask to the outputs — what a first prefill records for replay."""
+def moe_router(x2d, w_router, dims: MoEDims):
+    """Top-k softmax routing over the router's width, with capacity.
+    x2d: (N, D) -> dispatch (N, E, C) one-hot and combine (N, E, C)
+    weights over the E experts held here; claims on experts held
+    elsewhere and overflowed claims drop (standard GShard behaviour)."""
     N = x2d.shape[0]
+    E = dims.num_experts
     logits = (x2d.astype(jnp.float32) @ w_router.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                    # (N, E)
+    probs = jax.nn.softmax(logits, axis=-1)                    # (N, R)
     gate_vals, gate_idx = lax.top_k(probs, dims.top_k)         # (N, k)
+    local = gate_idx - dims.first      # outside [0, E): held elsewhere
     # position of each (token, slot) within its expert queue
-    onehot = jax.nn.one_hot(gate_idx, dims.num_experts,
-                            dtype=jnp.int32)                   # (N, k, E)
-    flat = onehot.reshape(N * dims.top_k, dims.num_experts)
-    if keep_override is None:
-        counted = flat
-    else:                              # only forced-keep claims queue up
-        counted = flat * keep_override.reshape(N * dims.top_k, 1) \
-            .astype(jnp.int32)
-    pos_in_expert = (jnp.cumsum(counted, axis=0) - counted) \
-        .reshape(N, dims.top_k, dims.num_experts)
+    onehot = jax.nn.one_hot(local, E, dtype=jnp.int32)         # (N, k, E)
+    flat = onehot.reshape(N * dims.top_k, E)
+    pos_in_expert = (jnp.cumsum(flat, axis=0) - flat) \
+        .reshape(N, dims.top_k, E)
     pos = jnp.sum(pos_in_expert * onehot, axis=-1)             # (N, k)
-    keep = pos < dims.capacity
-    if keep_override is not None:
-        keep = keep & keep_override
-    disp = (jax.nn.one_hot(gate_idx, dims.num_experts, dtype=x2d.dtype)
+    keep = (local >= 0) & (local < E) & (pos < dims.capacity)
+    disp = (jax.nn.one_hot(local, E, dtype=x2d.dtype)
             * keep[..., None].astype(x2d.dtype))               # (N,k,E)
     cap_onehot = jax.nn.one_hot(pos, dims.capacity, dtype=x2d.dtype)
     dispatch = jnp.einsum("nke,nkc->nec", disp, cap_onehot)    # (N,E,C)
     combine = jnp.einsum("nke,nkc,nk->nec", disp, cap_onehot,
                          gate_vals.astype(x2d.dtype))
-    aux = _load_balance_loss(probs, gate_idx, dims)
-    if return_keep:
-        return dispatch, combine, aux, keep
-    return dispatch, combine, aux
+    return dispatch, combine, _load_balance_loss(probs, gate_idx)
 
 
-def _load_balance_loss(probs, gate_idx, dims: MoEDims):
+def _load_balance_loss(probs, gate_idx):
     """Switch-style auxiliary load-balancing loss."""
-    N = probs.shape[0]
+    R = probs.shape[-1]                  # the router's width
     me = jnp.mean(probs, axis=0)
-    hits = jax.nn.one_hot(gate_idx[:, 0], dims.num_experts)
+    hits = jax.nn.one_hot(gate_idx[:, 0], R)
     ce = jnp.mean(hits, axis=0)
-    return dims.num_experts * jnp.sum(me * ce)
+    return R * jnp.sum(me * ce)
 
 
-def moe_ffn_dense(x2d, p, dims: MoEDims, *, keep_override=None,
-                  return_keep=False):
+def moe_ffn_dense(x2d, p, dims: MoEDims):
     """Reference dispatch -> per-expert SwiGLU -> combine via (N, E, C)
     one-hot einsums (GShard formulation). O(N*E*C) memory: oracle /
     smoke-scale only — the production path is moe_ffn below."""
-    routed = moe_router(x2d, p["router"], dims,
-                        keep_override=keep_override,
-                        return_keep=return_keep)
-    dispatch, combine, aux = routed[:3]
+    dispatch, combine, aux = moe_router(x2d, p["router"], dims)
     xe = jnp.einsum("nec,nd->ecd", dispatch, x2d)              # (E, C, D)
     h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, p["w_gate"])) \
         * jnp.einsum("ecd,edf->ecf", xe, p["w_up"])
     ye = jnp.einsum("ecf,efd->ecd", h, p["w_down"])            # (E, C, D)
     y = jnp.einsum("nec,ecd->nd", combine, ye)
-    if return_keep:
-        return y, aux, routed[3]
     return y, aux
 
 
-def moe_ffn(x2d, p, dims: MoEDims, *, keep_override=None,
-            return_keep=False):
+def moe_ffn(x2d, p, dims: MoEDims, *, return_keep=False):
     """Group-local sort/scatter dispatch -> grouped SwiGLU -> combine.
 
     O(N*k*D) memory (no (N, E, C) one-hots). Tokens are dispatched within
@@ -493,15 +502,22 @@ def moe_ffn(x2d, p, dims: MoEDims, *, keep_override=None,
     per group (C/G), the standard GShard data-parallel drop rule; with
     G == 1 the result is bit-identical to moe_ffn_dense.
 
+    The router scores all of its experts; only the E held here
+    (``dims.first`` onwards) are computed, and claims on the others are
+    left out — the share of the layer one chip of an expert-parallel
+    group gives, without the exchange.
+
     The (E, C, D) expert batch is the paper's tile pool: one tile per
     expert, executed as a grouped weight-stationary GEMM (kernels.
     packed_mvm on TPU), experts sharded across D_h = the model axis.
 
-    ``keep_override`` / ``return_keep`` mirror moe_router: the override
-    replays a recorded drop population (queue positions are counted over
-    forced-keep claims only), ``return_keep`` appends the realized
-    (N, K) keep mask — with G == 1 both are bit-compatible with the
-    dense path.
+    ``return_keep`` appends the realized (N, K) keep mask: claims on held
+    experts within capacity.
+
+    At a capacity of N or more (``moe_dims_dropless``) no claim can
+    overflow and a dispatch would fill E x N rows anyway, so there is
+    none: every held expert runs every token, weighted by its gate where
+    the token claimed it and by zero elsewhere.
     """
     N, D = x2d.shape
     E, K, C = dims.num_experts, dims.top_k, dims.capacity
@@ -511,36 +527,33 @@ def moe_ffn(x2d, p, dims: MoEDims, *, keep_override=None,
     n, Cg = N // G, C // G
 
     logits = x2d.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                    # (N, E)
+    probs = jax.nn.softmax(logits, axis=-1)                    # (N, R)
     gate_vals, gate_idx = lax.top_k(probs, K)                  # (N, K)
-    aux = _load_balance_loss(probs, gate_idx, dims)
+    aux = _load_balance_loss(probs, gate_idx)
+    local = gate_idx - dims.first
+    held = (local >= 0) & (local < E)
+
+    if C >= N:                                                 # dropless
+        gates = jnp.einsum("nke,nk->en", jax.nn.one_hot(local, E),
+                           jnp.where(held, gate_vals, 0.0))    # (E, N)
+        h = jax.nn.silu(jnp.einsum("nd,edf->enf", x2d, p["w_gate"])) \
+            * jnp.einsum("nd,edf->enf", x2d, p["w_up"])
+        h = shard_hint(h * gates[..., None].astype(h.dtype), "tp", "dp", None)
+        y = jnp.einsum("enf,efd->nd", h, p["w_down"]).astype(x2d.dtype)
+        y = shard_hint(y, "dp", None)
+        return (y, aux, held) if return_keep else (y, aux)
 
     # --- group-local dispatch (vmapped over G) --------------------------------
-    e_flat = gate_idx.reshape(G, n * K)
+    e_flat = jnp.where(held, local, E).reshape(G, n * K)       # E: elsewhere
     t_flat = jnp.arange(n * K, dtype=jnp.int32) // K           # local rows
     order = jnp.argsort(e_flat, axis=-1, stable=True)          # (G, n*K)
     e_sorted = jnp.take_along_axis(e_flat, order, axis=-1)
-    counts = jax.vmap(lambda e: jnp.bincount(e, length=E))(e_flat)
-    offsets = jnp.cumsum(counts, axis=-1) - counts             # (G, E)
-    if keep_override is None:
-        pos = jnp.arange(n * K, dtype=jnp.int32)[None] \
-            - jnp.take_along_axis(offsets, e_sorted, axis=-1)
-        keep = pos < Cg
-    else:
-        # replay: queue positions counted over forced-keep claims only.
-        # e_sorted is expert-sorted within each group, so an exclusive
-        # cumsum of the forced mask minus its value at the expert's
-        # segment start is the within-expert queue position. offsets[e]
-        # can be n*K for empty trailing experts — pad with the total.
-        f_sorted = jnp.take_along_axis(
-            keep_override.reshape(G, n * K), order, axis=-1)
-        fi = f_sorted.astype(jnp.int32)
-        csum = jnp.cumsum(fi, axis=-1) - fi                    # exclusive
-        csum_pad = jnp.concatenate(
-            [csum, jnp.sum(fi, axis=-1, keepdims=True)], axis=-1)
-        starts = jnp.take_along_axis(csum_pad, offsets, axis=-1)
-        pos = csum - jnp.take_along_axis(starts, e_sorted, axis=-1)
-        keep = f_sorted & (pos < Cg)
+    counts = jax.vmap(lambda e: jnp.bincount(e, length=E + 1))(e_flat)
+    offsets = jnp.cumsum(counts, axis=-1) - counts             # (G, E+1)
+    pos = jnp.arange(n * K, dtype=jnp.int32)[None] \
+        - jnp.take_along_axis(offsets, e_sorted, axis=-1)
+    keep = (e_sorted < E) & (pos < Cg)
+    e_sorted = jnp.where(keep, e_sorted, 0)
     pos_c = jnp.where(keep, pos, Cg)                           # Cg = trash
     xg = shard_hint(x2d.reshape(G, n, D), "dp", None, None)
     x_rep = jnp.take_along_axis(
@@ -594,7 +607,7 @@ def init_moe_params(key, cfg, d_model):
     ks = jax.random.split(key, 4)
     E, F = m.num_experts, m.d_ff_expert
     p = {
-        "router": dense_init(ks[0], d_model, E),
+        "router": dense_init(ks[0], d_model, m.router_width),
         "w_gate": trunc_normal(ks[1], (E, d_model, F),
                                std=1.0 / math.sqrt(d_model)),
         "w_up": trunc_normal(ks[2], (E, d_model, F),

@@ -1,10 +1,17 @@
 """MoE decoder models: olmoe-1b-7b (GQA + 64e top-8) and
-deepseek-v2-lite-16b (MLA latent attention + 2 shared / 64 routed top-6).
+deepseek-v2-lite-16b (MLA latent attention with YaRN rope; a leading dense
+layer, then 2 shared / 64 routed top-6).
 
 MLA decode uses the *absorbed* formulation: the KV cache stores only the
 compressed latent (kv_lora_rank + rope head) per token — the paper-adjacent
 "pack the stationary operand small" idea applied to the KV cache — and
 W_uk / W_uv are folded into the query/output projections at decode time.
+
+Layers ``0 .. first_k_dense - 1`` carry a dense SwiGLU of ``d_ff`` in
+``params["dense"]``; the MoE layers after them are stacked in
+``params["blocks"]`` and run in one ``lax.scan``. Serving (``prefill``,
+``paged_prefill``, the decode steps) routes dropless; ``forward`` keeps
+the capacity factor for training.
 """
 
 from __future__ import annotations
@@ -23,11 +30,14 @@ from .transformer import _default_batch, _embed, _head
 # --- params ----------------------------------------------------------------------
 
 def init_params(cfg, key):
+    """Keys as the reference regenerates them: (embed, blocks, head) from
+    ``key``, one key per layer from the blocks key (dense layers first),
+    ten per layer, of which 2..7 make the attention and 8 the FFN."""
     D, V = cfg.d_model, cfg.vocab_size
     norm_init, _ = L.make_norm(cfg)
     k_embed, k_blocks, k_head = jax.random.split(key, 3)
 
-    def block_init(k):
+    def block_init(k, dense=False):
         ks = jax.random.split(k, 10)
         p = {"ln1": norm_init(ks[0], D), "ln2": norm_init(ks[1], D)}
         if cfg.mla is not None:
@@ -50,16 +60,38 @@ def init_params(cfg, key):
             p["wk"] = L.dense_init(ks[3], D, cfg.kv_dim)
             p["wv"] = L.dense_init(ks[4], D, cfg.kv_dim)
             p["wo"] = L.dense_init(ks[5], cfg.q_dim, D)
-        p["moe"] = L.init_moe_params(ks[8], cfg, D)
+        if dense:
+            kf = jax.random.split(ks[8], 3)
+            p["mlp"] = {"w_gate": L.dense_init(kf[0], D, cfg.d_ff),
+                        "w_up": L.dense_init(kf[1], D, cfg.d_ff),
+                        "w_down": L.dense_init(kf[2], cfg.d_ff, D)}
+        else:
+            p["moe"] = L.init_moe_params(ks[8], cfg, D)
         return p
 
-    blocks = jax.vmap(block_init)(jax.random.split(k_blocks, cfg.num_layers))
-    return {
+    keys = jax.random.split(k_blocks, cfg.num_layers)
+    nd = cfg.first_k_dense
+    out = {
         "embed": L.trunc_normal(k_embed, (V, D)),
-        "blocks": blocks,
+        "blocks": jax.vmap(block_init)(keys[nd:]),
         "ln_f": norm_init(k_head, D),
         "lm_head": L.dense_init(k_head, D, V),
     }
+    if nd:
+        out["dense"] = jax.vmap(lambda k: block_init(k, dense=True))(
+            keys[:nd])
+    return out
+
+
+def softmax_scale(cfg) -> float:
+    """MLA's score scale: 1/sqrt(nope + rope head), times YaRN's mscale
+    squared where the rope is scaled with ``mscale_all_dim``."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= L.yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
 
 
 # --- attention variants ------------------------------------------------------------
@@ -71,8 +103,10 @@ def _gqa_part(cfg, p, h, batch, mask, cache, cache_pos):
     q = (h @ p["wq"].astype(cd)).reshape(B, S, cfg.num_heads, dh)
     k = (h @ p["wk"].astype(cd)).reshape(B, S, cfg.num_kv_heads, dh)
     v = (h @ p["wv"].astype(cd)).reshape(B, S, cfg.num_kv_heads, dh)
-    q = L.apply_rope(q, batch["positions"], cfg.rope_theta)
-    k = L.apply_rope(k, batch["positions"], cfg.rope_theta)
+    q = L.apply_rope(q, batch["positions"], cfg.rope_theta,
+                     cfg.rope_scaling)
+    k = L.apply_rope(k, batch["positions"], cfg.rope_theta,
+                     cfg.rope_scaling)
     if cache is not None:
         ck, cv = cache
         k = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
@@ -99,11 +133,13 @@ def _mla_part(cfg, p, h, batch, mask, cache, cache_pos):
 
     q = (h @ p["wq"].astype(cd)).reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = L.apply_rope(q_rope, batch["positions"], cfg.rope_theta)
+    q_rope = L.apply_rope(q_rope, batch["positions"], cfg.rope_theta,
+                          cfg.rope_scaling)
 
     c_kv = L.rmsnorm(h @ p["w_dkv"].astype(cd), p["kv_ln"])   # (B,S,r)
     k_rope = L.apply_rope((h @ p["w_kr"].astype(cd))[:, :, None, :],
-                          batch["positions"], cfg.rope_theta)  # (B,S,1,dr)
+                          batch["positions"], cfg.rope_theta,
+                          cfg.rope_scaling)                     # (B,S,1,dr)
 
     latent = jnp.concatenate([c_kv, k_rope[:, :, 0, :]], axis=-1)
     if cache is not None:
@@ -117,7 +153,7 @@ def _mla_part(cfg, p, h, batch, mask, cache, cache_pos):
     # absorbed scores: q_nope (B,S,H,dn) @ w_uk^T (H,dn,r) -> (B,S,H,r)
     q_lat = jnp.einsum("bshd,hrd->bshr", q_nope,
                        p["w_uk"].astype(cd))
-    scale = 1.0 / math.sqrt(dn + dr)
+    scale = softmax_scale(cfg)
 
     def scores_chunk(ql, qr, q0, qc):
         s = (jnp.einsum("bshr,btr->bhst", ql, c_all)
@@ -153,35 +189,39 @@ def _mla_part(cfg, p, h, batch, mask, cache, cache_pos):
 
 # --- block ------------------------------------------------------------------------
 
-def _moe_ffn_tail(cfg, p, y, dims, route_keep=None, return_keep=False):
-    """Second half of every MoE block (lockstep AND paged decode share
-    this, so shared-expert / dispatch changes cannot diverge the paths):
-    norm -> routed expert FFN (+ shared experts) -> residual.
-
-    ``route_keep`` ((B, S, k) bool) replays a recorded drop population
-    (re-prefill after preemption); ``return_keep`` appends the realized
-    (B, S, k) keep mask for the engine to record."""
+def _ffn_tail(cfg, p, y, dims, return_keep=False):
+    """Second half of every block (lockstep AND paged decode share this,
+    so shared-expert / dispatch changes cannot diverge the paths): norm ->
+    the dense SwiGLU of a leading layer, or the routed expert FFN (+
+    shared experts) -> residual. ``return_keep`` appends the (B, S, k)
+    mask of the claims computed on experts held here (None for a dense
+    layer)."""
     _, norm = L.make_norm(cfg)
     B, S, D = y.shape
     cd = L.COMPUTE_DTYPE
-    h2 = norm(y, p["ln2"]).astype(cd)
-    mp = jax.tree.map(lambda a: a.astype(cd), p["moe"])
-    out = L.moe_ffn(h2.reshape(B * S, D), mp, dims,
-                    keep_override=None if route_keep is None
-                    else route_keep.reshape(B * S, -1),
-                    return_keep=return_keep)
-    ff, aux = out[0], out[1]
-    if cfg.moe.num_shared_experts:
-        ff = ff + L.swiglu(h2.reshape(B * S, D), mp["shared_gate"],
-                           mp["shared_up"], mp["shared_down"])
+    h2 = norm(y, p["ln2"]).astype(cd).reshape(B * S, D)
+    keep = None
+    if "mlp" in p:
+        mp = jax.tree.map(lambda a: a.astype(cd), p["mlp"])
+        ff = L.swiglu(h2, mp["w_gate"], mp["w_up"], mp["w_down"])
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        mp = jax.tree.map(lambda a: a.astype(cd), p["moe"])
+        out = L.moe_ffn(h2, mp, dims, return_keep=return_keep)
+        ff, aux = out[0], out[1]
+        if return_keep:
+            keep = out[2].reshape(B, S, -1)
+        if cfg.moe.num_shared_experts:
+            ff = ff + L.swiglu(h2, mp["shared_gate"], mp["shared_up"],
+                               mp["shared_down"])
     res = y + ff.reshape(B, S, D).astype(y.dtype)
     if return_keep:
-        return res, aux, out[2].reshape(B, S, -1)
+        return res, aux, keep
     return res, aux
 
 
 def _block(cfg, p, x, batch, mask, dims, cache=None, cache_pos=None,
-           constrain=None, route_keep=None, return_keep=False):
+           constrain=None):
     _, norm = L.make_norm(cfg)
     cd = L.COMPUTE_DTYPE
     h = norm(x, p["ln1"]).astype(cd)
@@ -195,65 +235,48 @@ def _block(cfg, p, x, batch, mask, dims, cache=None, cache_pos=None,
     if constrain is not None:
         attn_out = constrain(attn_out)
     y = x + attn_out.astype(x.dtype)
-
-    tail = _moe_ffn_tail(cfg, p, y, dims, route_keep=route_keep,
-                         return_keep=return_keep)
-    out, aux = tail[0], tail[1]
+    out, aux = _ffn_tail(cfg, p, y, dims)
     if constrain is not None:
         out = constrain(out)
-    if return_keep:
-        return out, kv, aux, tail[2]
     return out, kv, aux
 
 
 # --- forward / loss ------------------------------------------------------------------
 
 def forward(cfg, params, batch, *, remat=False, constrain=None,
-            return_kv=False, return_aux=False, route_capacity=None,
-            route_keep=None, return_route_keep=False):
-    """``route_capacity`` overrides the expert-capacity ceiling (a static
-    Python int, so callers key it into the jit cache): serving paths pass
-    ``moe_dims(cfg, exact_live_tokens).capacity`` when the batch is
-    padded, keeping the engine's drop decisions identical to the
-    exact-length oracle's. Trailing pads can claim capacity only AFTER
-    every live token (claims are in token order), so a tight ceiling
-    never displaces a live token in favour of a pad.
-
-    ``route_keep`` ((L, B, S, k) bool) REPLAYS a recorded per-layer drop
-    population — the re-prefill-after-preemption path — and
-    ``return_route_keep`` appends the realized (L, B, S, k) masks so a
-    first prefill can record them."""
+            return_kv=False, return_aux=False, dropless=False):
+    """Logits over ``batch``; ``return_kv`` appends every layer's cache
+    entries in layer order (MLA: latents (L, B, S, r+dr); GQA: (K, V)).
+    Training routes with the capacity factor (``moe_dims``); serving
+    passes ``dropless`` (``moe_dims_dropless``): no claim on a held
+    expert is ever dropped, so a prompt's output does not depend on its
+    bucket padding."""
     batch = _default_batch(cfg, batch)
     x = _embed(cfg, params, batch)
     B, S, D = x.shape
     mask = L.causal_mask(S, S) if S <= L.ATTN_CHUNK_THRESHOLD else None
-    dims = L.moe_dims(cfg, B * S) if route_capacity is None \
-        else dataclasses.replace(L.moe_dims(cfg, B * S),
-                                 capacity=route_capacity)
+    dims = (L.moe_dims_dropless if dropless else L.moe_dims)(cfg, B * S)
 
-    def body(carry, xs):
-        p, rk = xs
-        blk = _block(cfg, p, carry, batch, mask, dims,
-                     constrain=constrain, route_keep=rk,
-                     return_keep=return_route_keep)
-        y, kv, aux = blk[0], blk[1], blk[2]
-        return y, (kv if return_kv else 0, aux,
-                   blk[3] if return_route_keep else 0)
+    def body(carry, p):
+        y, kv, aux = _block(cfg, p, carry, batch, mask, dims,
+                            constrain=constrain)
+        return y, (kv if return_kv else 0, aux)
 
     if remat:
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.nothing_saveable)
-    x, (kvs, auxs, keeps) = lax.scan(body, x,
-                                     (params["blocks"], route_keep))
+    dense_kvs = None
+    if cfg.first_k_dense:
+        x, (dense_kvs, _) = lax.scan(body, x, params["dense"])
+    x, (kvs, auxs) = lax.scan(body, x, params["blocks"])
     logits = _head(cfg, params, x)
     aux = jnp.mean(auxs)
     out = [logits]
-    if return_kv:
-        out.append(kvs)
+    if return_kv:       # dense layers first, along the layer axis
+        out.append(kvs if dense_kvs is None else jax.tree.map(
+            lambda a, b: jnp.concatenate([a, b]), dense_kvs, kvs))
     if return_aux:
         out.append(aux)
-    if return_route_keep:
-        out.append(keeps)
     return tuple(out) if len(out) > 1 else logits
 
 
@@ -294,7 +317,8 @@ def prefill(cfg, params, batch, cache_len: int, *, constrain=None,
     tokens = batch["tokens"]
     B, S = tokens.shape
     logits, kvs, _ = forward(cfg, params, batch, return_kv=True,
-                             return_aux=True, constrain=constrain)
+                             return_aux=True, constrain=constrain,
+                             dropless=True)
     if cfg.mla is not None:
         pad = [(0, 0), (0, 0), (0, cache_len - S), (0, 0)]
         kv = jnp.pad(kvs.astype(L.COMPUTE_DTYPE), pad)
@@ -310,6 +334,7 @@ def decode_step(cfg, params, state: MoEDecodeState, tokens, *,
                 constrain=None):
     B = tokens.shape[0]
     pos = state.pos
+    nd = cfg.first_k_dense
     T = state.kv.shape[-2] if cfg.mla is not None else state.kv.shape[-3]
     batch = _default_batch(cfg, {"tokens": tokens[:, None],
                                  "positions": jnp.full((B, 1), pos,
@@ -327,17 +352,29 @@ def decode_step(cfg, params, state: MoEDecodeState, tokens, *,
             y, kv, _ = _block(cfg, p, carry, batch, mask5, dims,
                               cache=cache, cache_pos=pos)
             return y, kv
-        x, kv_new = lax.scan(body, x, (params["blocks"], state.kv))
+        caches = (state.kv[:nd], state.kv[nd:]) if nd else (None, state.kv)
     else:
         def body(carry, xs):
             p, ck, cv = xs
             y, (k, v), _ = _block(cfg, p, carry, batch, mask5, dims,
                                   cache=(ck, cv), cache_pos=pos)
             return y, (k, v)
-        x, (k_new, v_new) = lax.scan(body, x,
-                                     (params["blocks"], state.kv[0],
-                                      state.kv[1]))
-        kv_new = jnp.stack([k_new, v_new])
+        caches = ((state.kv[:, :nd], state.kv[:, nd:]) if nd
+                  else (None, state.kv))
+
+    def run(x, stack, kv):
+        if cfg.mla is not None:
+            return lax.scan(body, x, (stack, kv))
+        x, (k_new, v_new) = lax.scan(body, x, (stack, kv[0], kv[1]))
+        return x, jnp.stack([k_new, v_new])
+
+    dense_new = None
+    if nd:
+        x, dense_new = run(x, params["dense"], caches[0])
+    x, kv_new = run(x, params["blocks"], caches[1])
+    if dense_new is not None:
+        kv_new = jnp.concatenate([dense_new, kv_new],
+                                 axis=0 if cfg.mla is not None else 1)
     logits = _head(cfg, params, x)[:, 0]
     return logits, MoEDecodeState(kv=kv_new, pos=pos + 1)
 
@@ -356,63 +393,77 @@ def latent_width(cfg) -> int:
 
 @dataclasses.dataclass
 class MoEPagedState:
-    kv_pages: jax.Array    # (L, P, page, r+dr); page 0 = trash page
+    """The latent page pools. A pool of n layers is (P, n * page * (r+dr)):
+    row ``page`` holds that page for every layer, each layer's tokens'
+    latent rows laid end to end at column ``layer * page * (r+dr)``. The
+    prefill's page writes are whole rows, the decode's token writes and
+    table gathers are one layer's columns of a row, and the pool keeps a
+    row-major layout on the chip. Page 0 is the trash page."""
+    kv_pages: jax.Array     # the MoE layers' pool
+    dense_pages: jax.Array  # the first_k_dense layers' own pool
+    claims: jax.Array       # () int32: live slots' claims on held experts
+    #                         since the decode dispatch began
 
 
-jax.tree_util.register_dataclass(MoEPagedState, data_fields=["kv_pages"],
-                                 meta_fields=[])
+jax.tree_util.register_dataclass(
+    MoEPagedState, data_fields=["kv_pages", "dense_pages", "claims"],
+    meta_fields=[])
 
 
 def init_paged_decode_state(cfg, num_pages: int, page_size: int,
                             dtype=L.COMPUTE_DTYPE) -> MoEPagedState:
     assert cfg.mla is not None, "paged decode pools the MLA latent cache"
-    return MoEPagedState(kv_pages=jnp.zeros(
-        (cfg.num_layers, num_pages, page_size, latent_width(cfg)), dtype))
+    nd = cfg.first_k_dense
+
+    def pool(n):
+        return jnp.zeros((num_pages, n * page_size * latent_width(cfg)),
+                         dtype)
+    return MoEPagedState(kv_pages=pool(cfg.num_layers - nd),
+                         dense_pages=pool(nd),
+                         claims=jnp.zeros((), jnp.int32))
 
 
-def paged_prefill(cfg, params, batch, lengths, *, constrain=None,
-                  route_capacity=None, route_keep=None):
+def paged_prefill(cfg, params, batch, lengths, *, constrain=None):
     """Forward the (padded) prompts; return per-sequence last-live-token
     logits plus the raw per-layer latents (L, B, S, r+dr) for page
-    scatter.
-
-    Pad positions never influence live ones through attention (causal),
-    and trailing pads can never displace a live token from an expert
-    (capacity is claimed in token order). ``route_capacity`` carries the
-    EXACT-length capacity ceiling (keyed into the jit cache as a static
-    arg by the engine backend), so the engine's drop decisions match the
-    exact-length oracle's even at a tight capacity_factor — without it
-    the shape-static ceiling would be computed from the padded bucket
-    and keep tokens the oracle drops.
-
-    ``route_keep`` replays a recorded (L, B, S, k) drop population (the
-    re-prefill-after-preemption path); the realized masks are always
-    returned last so a first prefill can record them."""
-    logits, kvs, _, keeps = forward(
-        cfg, params, batch, return_kv=True, return_aux=True,
-        constrain=constrain, route_capacity=route_capacity,
-        route_keep=route_keep, return_route_keep=True)
+    scatter. Routing is dropless, so trailing pads neither displace a
+    live token from an expert nor change its output: one program serves
+    every prompt length of a bucket."""
+    logits, kvs, _ = forward(cfg, params, batch, return_kv=True,
+                             return_aux=True, constrain=constrain,
+                             dropless=True)
     idx = (lengths - 1)[:, None, None]
     last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
-    return last, kvs.astype(L.COMPUTE_DTYPE), keeps
+    return last, kvs.astype(L.COMPUTE_DTYPE)
 
 
 def write_prefill_pages(cfg, state: MoEPagedState, latents, page_ids
                         ) -> MoEPagedState:
     """Scatter one prefilled request's latents into its pages. latents:
-    (L, S, r+dr), S a page multiple; page_ids (S/page,) int32 with dead
-    entries pointing at the trash page."""
-    Lc, P, page, width = state.kv_pages.shape
-    chunks = latents.reshape(Lc, -1, page, width)
-    return MoEPagedState(kv_pages=state.kv_pages.at[:, page_ids].set(
-        chunks.astype(state.kv_pages.dtype)))
+    (L, S, r+dr) in layer order, S a page multiple; page_ids (S/page,)
+    int32 with dead entries pointing at the trash page."""
+    nd = cfg.first_k_dense
+    n_pages = page_ids.shape[0]
+
+    def write(pool, lat):
+        rows = lat.reshape(lat.shape[0], n_pages, -1).swapaxes(0, 1)
+        return pool.at[page_ids].set(
+            rows.reshape(n_pages, -1).astype(pool.dtype))
+
+    return dataclasses.replace(state,
+                               kv_pages=write(state.kv_pages, latents[nd:]),
+                               dense_pages=write(state.dense_pages,
+                                                 latents[:nd]))
 
 
-def _mla_paged_block(cfg, p, x, batch, pages, page_table, page_ids,
-                     offsets, pos, dims):
-    """One MLA + MoE block over the paged latent cache, S == 1. pages:
-    (P, page, r+dr) for this layer; the new token's latent is appended at
-    (page_ids, offsets) before the absorbed-score gather."""
+def _mla_paged_block(cfg, p, x, batch, pool, col, page_w, page_table,
+                     page_ids, offsets, pos, active, dims):
+    """One MLA block over the paged latent cache, S == 1. pool: a
+    ``MoEPagedState`` pool whose columns ``col .. col + page_w - 1`` (a
+    page of latent rows) are this block's layer; the new token's latent is written at
+    (page_ids, offsets) in place before the absorbed-score gather of the
+    slots' table pages. Returns the block's output, the pool and the
+    live slots' claims on held experts (0 for a dense layer)."""
     m = cfg.mla
     _, norm = L.make_norm(cfg)
     B, S, D = x.shape
@@ -424,22 +475,38 @@ def _mla_paged_block(cfg, p, x, batch, pages, page_table, page_ids,
     h = norm(x, p["ln1"]).astype(cd)
     q = (h @ p["wq"].astype(cd)).reshape(B, 1, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = L.apply_rope(q_rope, batch["positions"], cfg.rope_theta)
+    q_rope = L.apply_rope(q_rope, batch["positions"], cfg.rope_theta,
+                          cfg.rope_scaling)
     c_kv = L.rmsnorm(h @ p["w_dkv"].astype(cd), p["kv_ln"])     # (B,1,r)
     k_rope = L.apply_rope((h @ p["w_kr"].astype(cd))[:, :, None, :],
-                          batch["positions"], cfg.rope_theta)   # (B,1,1,dr)
+                          batch["positions"], cfg.rope_theta,
+                          cfg.rope_scaling)                     # (B,1,1,dr)
     latent = jnp.concatenate([c_kv[:, 0], k_rope[:, 0, 0]], axis=-1)
-    pages = pages.at[page_ids, offsets].set(latent.astype(pages.dtype))
+    w = latent.shape[-1]
+    # one (1, r+dr) window per slot: element-wise indices would make the
+    # compiler flatten the pool
+    pool = lax.scatter(
+        pool, jnp.stack([page_ids, col + offsets * w], axis=-1),
+        latent.astype(pool.dtype), lax.ScatterDimensionNumbers(
+            update_window_dims=(1,), inserted_window_dims=(0,),
+            scatter_dims_to_operand_dims=(0, 1)),
+        mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
 
-    g = pages[page_table]                       # (B, M, page, r+dr)
-    T = g.shape[1] * g.shape[2]
-    g = g.reshape(B, T, -1).astype(cd)
+    starts = jnp.stack([page_table, jnp.broadcast_to(col, page_table.shape)],
+                       axis=-1)
+    g = lax.gather(                             # (B, M, page * (r+dr))
+        pool, starts, lax.GatherDimensionNumbers(
+            offset_dims=(2,), collapsed_slice_dims=(0,),
+            start_index_map=(0, 1)),
+        slice_sizes=(1, page_w),
+        mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    T = g.shape[1] * g.shape[2] // w
+    g = g.reshape(B, T, w).astype(cd)
     c_all, kr_all = g[..., :r], g[..., r:]
     q_lat = jnp.einsum("bshd,hrd->bshr", q_nope, p["w_uk"].astype(cd))
-    scale = 1.0 / math.sqrt(dn + dr)
     s = (jnp.einsum("bshr,btr->bhst", q_lat, c_all)
          + jnp.einsum("bshd,btd->bhst", q_rope, kr_all))
-    s = s.astype(jnp.float32) * scale
+    s = s.astype(jnp.float32) * softmax_scale(cfg)
     # linear page table: entry (row, off) holds absolute position
     # row*page + off, so "<= pos" is the whole validity story (rows past
     # the live pages are trash but their positions already exceed pos);
@@ -451,8 +518,10 @@ def _mla_paged_block(cfg, p, x, batch, pages, page_table, page_ids,
     attn = jnp.einsum("bshr,hrd->bshd", out_lat, p["w_uv"].astype(cd))
     y = x + (attn.reshape(B, 1, H * dv) @ p["wo"].astype(cd)) \
         .astype(x.dtype)
-    out, _ = _moe_ffn_tail(cfg, p, y, dims)
-    return out, pages
+    out, _, keep = _ffn_tail(cfg, p, y, dims, return_keep=True)
+    claims = (jnp.zeros((), jnp.int32) if keep is None
+              else jnp.sum(keep[:, 0] & active[:, None], dtype=jnp.int32))
+    return out, pool, claims
 
 
 def paged_decode_step(cfg, params, state: MoEPagedState, tokens,
@@ -460,11 +529,13 @@ def paged_decode_step(cfg, params, state: MoEPagedState, tokens,
     """One token per slot against the paged latent cache. tokens (B,)
     int32; page_table (B, M) int32; lengths (B,) live context per slot;
     active (B,) bool — inactive slots write to the trash page and read a
-    single masked entry. Lengths are advanced by the caller."""
+    single masked entry. Lengths are advanced by the caller; the live
+    slots' claims on held experts are added to ``state.claims``."""
     del constrain
     assert cfg.mla is not None
     B = tokens.shape[0]
-    page = state.kv_pages.shape[2]
+    page_w = state.kv_pages.shape[1] // (cfg.num_layers - cfg.first_k_dense)
+    page = page_w // latent_width(cfg)
     pos = jnp.where(active, lengths.astype(jnp.int32), 0)
     batch = _default_batch(cfg, {"tokens": tokens[:, None],
                                  "positions": pos[:, None]})
@@ -477,26 +548,46 @@ def paged_decode_step(cfg, params, state: MoEPagedState, tokens,
     # requests, so batch composition must never cause an expert drop
     dims = L.moe_dims_dropless(cfg, B)
 
-    def body(carry, xs):
-        p, pages = xs
-        y, pages = _mla_paged_block(cfg, p, carry, batch, pages,
-                                    page_table, page_ids, offsets, pos,
-                                    dims)
-        return y, pages
+    def layers(x, claims, stack, pool):
+        """The blocks of ``stack`` over their own pool, which rides in the
+        scan's carry so each layer's write lands in place."""
+        def body(carry, p):
+            x, claims, pool, col = carry
+            y, pool, c = _mla_paged_block(cfg, p, x, batch, pool, col,
+                                          page_w, page_table, page_ids,
+                                          offsets, pos, active, dims)
+            return (y, claims + c, pool, col + page_w), None
+        (x, claims, pool, _), _ = lax.scan(
+            body, (x, claims, pool, jnp.zeros((), jnp.int32)), stack)
+        return x, claims, pool
 
-    x, kv_new = lax.scan(body, x, (params["blocks"], state.kv_pages))
+    claims, dense_pages = state.claims, state.dense_pages
+    if cfg.first_k_dense:
+        x, claims, dense_pages = layers(x, claims, params["dense"],
+                                        dense_pages)
+    x, claims, kv_pages = layers(x, claims, params["blocks"],
+                                 state.kv_pages)
     logits = _head(cfg, params, x)[:, 0]
-    return logits, MoEPagedState(kv_pages=kv_new)
+    return logits, MoEPagedState(kv_pages=kv_pages, dense_pages=dense_pages,
+                                 claims=claims)
+
+
+def expert_rows_per_step(cfg, num_slots: int) -> int:
+    """Expert rows one decode step computes for ``num_slots`` slots: every
+    MoE layer runs each held expert at the dropless capacity."""
+    dims = L.moe_dims_dropless(cfg, num_slots)
+    return ((cfg.num_layers - cfg.first_k_dense) * dims.num_experts
+            * dims.capacity)
 
 
 def paged_decode_multi(cfg, params, state: MoEPagedState, pending,
                        lengths, remaining, page_table, mask, h, *,
                        hmax: int, teacher=None):
     """Up to ``h`` fused ``paged_decode_step``s against the latent pages
-    (layers.multi_step_decode) with on-device sampling. Decode routing
-    is dropless, so the fused steps need no route trace — only prefill
-    records/replays expert drops."""
+    (layers.multi_step_decode) with on-device sampling. ``state.claims``
+    counts the dispatch's claims on held experts from zero."""
     def step(s, toks, pt, lens, act):
         return paged_decode_step(cfg, params, s, toks, pt, lens, act)
+    state = dataclasses.replace(state, claims=jnp.zeros((), jnp.int32))
     return L.multi_step_decode(step, hmax, state, pending, lengths,
                                remaining, page_table, mask, h, teacher)

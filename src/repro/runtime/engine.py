@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -254,6 +254,9 @@ class EngineReport:
 #                   (a slot holds at most R pages; on wrap the engine
 #                   frees the page that slid out of the window)
 #   page_bytes   -- HBM bytes one page holds across layers (0 if unpaged)
+#   expert_rows_per_step -- expert rows a decode step computes; nonzero
+#                   only for routed experts over latent pages, whose
+#                   decode state counts the live claims on held experts
 #   supports(cfg)     -- classmethod: can this backend serve the config
 #   can_ever_fit(...) -- admission feasibility for this cache shape
 #   admission_rows(pgr, ctx_len) -> table rows the prefill pages fill
@@ -273,21 +276,6 @@ def _bucket_prompt(ctx: np.ndarray, ecfg: EngineConfig, pages: list[int],
     pids = np.full((bucket // ecfg.page_size,), TRASH_PAGE, np.int32)
     pids[first_page:first_page + len(pages)] = pages
     return toks, pids
-
-
-def _routed_prefill(backend, req, ctx, slot, pages) -> np.ndarray:
-    """Prefill dispatch that records/replays MoE routing: a routed
-    backend's FIRST prefill of a request stores the realized expert
-    drop mask on the request; every re-prefill after preemption replays
-    it, so pooled output is token-for-token equal across preemption even
-    at a tight capacity_factor."""
-    if not getattr(backend, "routed", False):
-        return backend.prefill(ctx, req.extras, slot, pages)
-    logits = backend.prefill(ctx, req.extras, slot, pages,
-                             replay=req.route_trace)
-    if req.route_trace is None:
-        req.route_trace = backend.last_route_trace
-    return logits
 
 
 class _FusedDecode:
@@ -318,7 +306,7 @@ class _PagedBackendBase(_FusedDecode):
 
     paged = True
     slot_state_bytes = 0               # no per-slot non-paged state
-    routed = False                     # no MoE drop population to replay
+    expert_rows_per_step = 0           # no routed experts, no latent pages
     prefix_sharing = False             # opt-in per backend (dense only)
 
     @classmethod
@@ -451,7 +439,7 @@ class RecurrentBackend(_FusedDecode):
     ring_rows = None
     page_bytes = 0
     slot_state_bytes = 0
-    routed = False
+    expert_rows_per_step = 0
     prefix_sharing = False
 
     @classmethod
@@ -613,17 +601,53 @@ class HybridBackend(_PagedBackendBase):
         return np.asarray(logits)
 
 
+@lru_cache(maxsize=8)
+def _latent_programs(cfg, horizon: int):
+    """``LatentBackend``'s jitted prefill, decode and fused decode for one
+    config. A full-width prefill takes seconds to compile for a v5e and
+    there is one per bucket, so engines of a config in a process (of the
+    last eight configs served) share them: a second engine (a reload, the
+    next run) compiles nothing."""
+    from ..models import moe as MoE
+
+    # The state leads each program's arguments: an output takes the
+    # sharding of the first argument whose sharding it matches, and the
+    # 2-D pools would otherwise take a 2-D weight's spelling of it, which
+    # keys a second compile of every program they reenter.
+    def prefill_write(state, params, batch, lengths, page_ids):
+        last, latents = MoE.paged_prefill(cfg, params, batch, lengths)
+        state = MoE.write_prefill_pages(cfg, state, latents[:, 0], page_ids)
+        return last[0], state
+
+    def decode(state, params, tokens, page_table, lengths, active):
+        state = dataclasses.replace(state, claims=jnp.zeros((), jnp.int32))
+        return MoE.paged_decode_step(cfg, params, state, tokens,
+                                     page_table, lengths, active)
+
+    def decode_multi(state, pending, lengths, remaining, params,
+                     page_table, mask, h, teacher):
+        return MoE.paged_decode_multi(cfg, params, state, pending, lengths,
+                                      remaining, page_table, mask, h,
+                                      hmax=horizon, teacher=teacher)
+
+    return (jax.jit(prefill_write, donate_argnums=(0,)),
+            jax.jit(decode, donate_argnums=(0,)),
+            jax.jit(decode_multi, donate_argnums=(0, 1, 2, 3)))
+
+
 class LatentBackend(_LinearPagedMixin):
     """MoE + MLA (deepseek): pages hold compressed latent rows.
 
     The cache entry per token is the absorbed-MLA latent (kv_lora_rank +
     rope head), not per-head K/V — the paper's pack-the-stationary-
     operand-small idea applied to the page pool, so page_bytes is
-    latent-width-sized. Table growth is linear like the dense backend;
-    expert weights are the residency planner's problem (per-expert slices
-    in the layer schedule), not the pager's."""
-
-    routed = True                      # records/replays MoE drop masks
+    latent-width-sized (``PagerConfig.page_bytes``). Table growth is
+    linear like the dense backend. Routing is dropless at prefill and
+    decode, so one prefill program serves each bucket and a re-prefill
+    after preemption reproduces the first. Engines of one config share
+    their programs (``_latent_programs``). ``expert_rows_per_step`` is
+    the expert rows one decode step computes; the engine reads the live
+    claims on held experts back with each dispatch's tokens."""
 
     @classmethod
     def supports(cls, cfg) -> bool:
@@ -635,82 +659,36 @@ class LatentBackend(_LinearPagedMixin):
         assert cfg.mla is not None, \
             "LatentBackend pages the MLA latent cache"
         self.cfg, self.params, self.ecfg = cfg, params, ecfg
-        self.page_bytes = (cfg.num_layers * ecfg.page_size
-                           * MoE.latent_width(cfg) * 2)
+        self.page_bytes = ecfg.pager.page_bytes(cfg)
+        self.expert_rows_per_step = MoE.expert_rows_per_step(
+            cfg, ecfg.num_slots)
         self.state = MoE.init_paged_decode_state(cfg, ecfg.num_pages,
                                                  ecfg.page_size)
-        self.last_route_trace: dict | None = None
-
-        def prefill_write(params, state, batch, lengths, page_ids,
-                          route_capacity, route_keep):
-            last, latents, keeps = MoE.paged_prefill(
-                cfg, params, batch, lengths,
-                route_capacity=route_capacity, route_keep=route_keep)
-            state = MoE.write_prefill_pages(cfg, state, latents[:, 0],
-                                            page_ids)
-            return last[0], keeps[:, 0], state
-
-        def decode(params, state, tokens, page_table, lengths, active):
-            return MoE.paged_decode_step(cfg, params, state, tokens,
-                                         page_table, lengths, active)
-
-        def decode_multi(params, state, pending, lengths, remaining,
-                         page_table, mask, h, teacher):
-            return MoE.paged_decode_multi(cfg, params, state, pending,
-                                          lengths, remaining, page_table,
-                                          mask, h, hmax=ecfg.horizon,
-                                          teacher=teacher)
-
-        self._decode_multi = jax.jit(decode_multi,
-                                     donate_argnums=(1, 2, 3, 4))
-        # route_capacity is static: the exact-length expert-capacity
-        # ceiling is keyed into the jit cache, so a padded bucket traces
-        # once per (bucket, capacity) pair — distinct lengths with the
-        # same ceiling share a trace — instead of inflating the ceiling
-        # to the padded token count. route_keep=None (fresh prefill) and
-        # route_keep=array (replay) are distinct pytrees, so the replay
-        # trace only compiles on the first routed-tenant preemption.
-        self._prefill = jax.jit(prefill_write, donate_argnums=(1,),
-                                static_argnums=(5,))
-        self._decode = jax.jit(decode, donate_argnums=(1,))
+        self._prefill, self._decode, self._decode_multi = \
+            _latent_programs(cfg, ecfg.horizon)
 
     def prefill(self, ctx: np.ndarray, extras, slot: int,
-                page_ids: list[int], replay: dict | None = None
-                ) -> np.ndarray:
-        """``replay`` is a route trace recorded by a previous prefill of
-        this request ({"keep": (L, plen0, k) bool, "capacity": int}): the
-        cached prompt keeps are forced, tokens generated since are forced
-        KEPT (decode is dropless, so the original run kept them all), and
-        pads are forced dropped — the re-prefill reproduces the original
-        expert assignment token-for-token. The replay ceiling is
-        capacity0 + new tokens (each token holds at most one claim per
-        expert), rounded up to bound the jit-trace count — extra slots
-        are never filled, so the rounding cannot change any output."""
-        from ..models import layers as L
-
+                page_ids: list[int]) -> np.ndarray:
         toks, pids = _bucket_prompt(ctx, self.ecfg, page_ids)
-        plen, bucket = len(ctx), toks.shape[1]
-        if replay is None:
-            cap = L.moe_dims(self.cfg, plen).capacity
-            keep_arg = None
-        else:
-            keep0 = np.asarray(replay["keep"], bool)   # (L, plen0, k)
-            Lc, plen0, k = keep0.shape
-            forced = np.zeros((Lc, 1, bucket, k), bool)
-            forced[:, 0, :plen0] = keep0
-            forced[:, 0, plen0:plen] = True
-            cap = -(-(int(replay["capacity"]) + plen - plen0) // 8) * 8
-            keep_arg = jnp.asarray(forced)
-        logits, keeps, self.state = self._prefill(
-            self.params, self.state, {"tokens": jnp.asarray(toks)},
-            jnp.asarray([plen], jnp.int32), jnp.asarray(pids),
-            cap, keep_arg)
-        if replay is None:
-            self.last_route_trace = {
-                "keep": np.asarray(keeps)[:, :plen], "capacity": cap}
-        else:
-            self.last_route_trace = replay
+        logits, self.state = self._prefill(
+            self.state, self.params, {"tokens": jnp.asarray(toks)},
+            jnp.asarray([len(ctx)], jnp.int32), jnp.asarray(pids))
         return np.asarray(logits)
+
+    def decode(self, tokens, page_table, lengths, active) -> jax.Array:
+        logits, self.state = self._decode(
+            self.state, self.params, jnp.asarray(tokens),
+            jnp.asarray(page_table), jnp.asarray(lengths),
+            jnp.asarray(active))
+        return logits
+
+    def decode_fused(self, pending, lengths, remaining, page_table, mask,
+                     h: int, teacher=None):
+        out, self.state, pending, lengths, remaining = self._decode_multi(
+            self.state, pending, lengths, remaining, self.params,
+            page_table, jnp.asarray(mask), jnp.asarray(h, jnp.int32),
+            None if teacher is None else jnp.asarray(teacher, jnp.int32))
+        return out, pending, lengths, remaining
 
 
 ENGINE_FAMILIES = {"dense": PagedTransformerBackend,
@@ -846,6 +824,19 @@ class Engine:
         ds = DeviceLoopState(B, M, self._home)
 
         page_bytes = self.backend.page_bytes
+        expert_rows = self.backend.expert_rows_per_step
+
+        def count_latent(h: int, claims) -> None:
+            """The latent path's counters for a dispatch of ``h`` steps,
+            taken before the lengths advance: live claims on held experts
+            against the expert rows computed, and the latent rows live
+            (each slot attends over its context and the new token) against
+            the rows the whole page table's gather reads, per layer."""
+            tracing.ROUTES.add(int(claims), h * expert_rows)
+            live = (int(lengths[active].sum()) * h
+                    + len(active) * h * (h + 1) // 2)
+            tracing.LATENT_READS.add(live, B * M * page * h)
+
         rep = EngineReport(
             name=f"engine/{self.cfg.name}", num_slots=B,
             page_bytes=page_bytes,
@@ -944,8 +935,8 @@ class Engine:
                                             ctx, req.extras, s, pages,
                                             sh_pages, sh_tokens)
                                     else:
-                                        logits = _routed_prefill(
-                                            self.backend, req, ctx, s, pages)
+                                        logits = self.backend.prefill(
+                                            ctx, req.extras, s, pages)
                                 bucket = e.prefill_bucket
                                 full = -(-len(ctx) // bucket) * bucket
                                 computed = 0 if sh_tokens >= len(ctx) else (
@@ -964,8 +955,8 @@ class Engine:
                             else:
                                 sched.pop_ready()
                                 with TraceAnnotation("engine.prefill"):
-                                    logits = _routed_prefill(
-                                        self.backend, req, ctx, s, None)
+                                    logits = self.backend.prefill(
+                                        ctx, req.extras, s, None)
                                 rep.prefill_calls += 1
                                 rep.prefill_tokens += len(ctx)
                                 req.prefills += 1
@@ -1090,7 +1081,13 @@ class Engine:
                                 ds.pending, ds.lengths, ds.remaining,
                                 ds.table, act, h)
                         with TraceAnnotation("engine.wait"):
-                            toks_h = np.asarray(out)   # ONE host sync
+                            if expert_rows:   # claims ride with the tokens
+                                toks_h, claims = jax.device_get(
+                                    (out, self.backend.state.claims))
+                            else:
+                                toks_h = np.asarray(out)   # ONE host sync
+                        if expert_rows:
+                            count_latent(h, claims)
                         ds.adopt(p_d, l_d, r_d)
                         ds.count(dispatches=1, syncs=1)
                         rep.decode_steps += h
@@ -1113,7 +1110,13 @@ class Engine:
                             logits = self.backend.decode(
                                 pending, page_table, lengths, act)
                         with TraceAnnotation("engine.wait"):
-                            logits = np.asarray(logits)
+                            if expert_rows:
+                                logits, claims = jax.device_get(
+                                    (logits, self.backend.state.claims))
+                            else:
+                                logits = np.asarray(logits)
+                        if expert_rows:
+                            count_latent(1, claims)
                         ds.count(dispatches=1, syncs=1,
                                  upload_bytes=page_table.nbytes)
                         rep.decode_steps += 1
@@ -1712,8 +1715,7 @@ class PooledEngine:
                             ctx, req.extras, s, pages, sh_pages,
                             sh_tokens)
                     else:
-                        logits = _routed_prefill(backend, req, ctx, s,
-                                                 pages)
+                        logits = backend.prefill(ctx, req.extras, s, pages)
                     full = (-(-len(ctx) // e.prefill_bucket)
                             * e.prefill_bucket)
                     computed = 0 if sh_tokens >= len(ctx) else (
@@ -1728,8 +1730,7 @@ class PooledEngine:
                         sharer.record(a, ctx, len(ctx), page_table[s])
                 else:
                     sched.pop_ready(req)
-                    logits = _routed_prefill(backend, req, ctx, s,
-                                             None)
+                    logits = backend.prefill(ctx, req.extras, s, None)
                     rep.prefill_calls += 1
                     rep.prefill_tokens += len(ctx)
                     req.prefills += 1

@@ -80,7 +80,12 @@ class PagerConfig:
                 and self.pages_for(context_len) <= num_pages - 1)
 
     def page_bytes(self, cfg, dtype_bytes: int = 2) -> int:
-        """HBM bytes one page holds across all layers, K and V."""
+        """HBM bytes one page holds across all layers: K and V, or for an
+        MLA config the one latent row (kv_lora_rank + rope head) per
+        token that its cache holds."""
+        if cfg.mla is not None:
+            return (cfg.num_layers * self.page_size * dtype_bytes
+                    * (cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim))
         return (2 * cfg.num_layers * self.page_size * cfg.num_kv_heads
                 * cfg.head_dim * dtype_bytes)
 

@@ -34,7 +34,6 @@ class Request:
     done_step: int = -1
     prefills: int = 0                  # 1 + number of preemption restarts
     truncated: bool = False            # hit the pager's max context
-    route_trace: dict | None = None    # MoE first-prefill routing (replay)
     shared_pages: int = 0              # pages admitted by reference
     cow_copies: int = 0                # divergence-write page copies
 

@@ -21,7 +21,16 @@ Counters are kept whether or not a profiler runs, in bounded rings of
 samples stamped with ``time.monotonic()``: ``DISPATCHES`` holds one sample
 per decode dispatch (``h`` steps run, ``live`` slots, the ``cause`` that
 bound ``h``, one of ``CAUSES``), ``PREFILLS`` one per prefill (``prompt``
-tokens, ``computed`` tokens after bucket padding). The rings belong to the
+tokens, ``computed`` tokens after bucket padding). A backend with routed
+experts over latent pages (``LatentBackend``) adds one sample per decode
+dispatch to each of two more: ``ROUTES`` (``claims``: the live slots'
+routed claims that landed on experts held here, summed on the device over
+the dispatch's steps and MoE layers and read back with its tokens;
+``rows``: the expert rows the program computed for them, every held
+expert at its dropless capacity) and ``LATENT_READS`` (``live``: the
+latent rows the live slots attend over, summed over steps; ``gathered``:
+the rows the steps gather, every slot times the page table's width in
+rows; both per layer). The rings belong to the
 process, as the profiler's buffer does, so a reader that never holds the
 engine finds them; it takes the samples of its own time window.
 """
@@ -64,3 +73,5 @@ class Ring:
 
 DISPATCHES = Ring(("h", "live", "cause"))
 PREFILLS = Ring(("prompt", "computed"))
+ROUTES = Ring(("claims", "rows"))
+LATENT_READS = Ring(("live", "gathered"))

@@ -158,27 +158,27 @@ def test_engine_completes_interleaved_requests(arch):
 
 
 def test_latent_engine_matches_oracle_at_tight_capacity():
-    """The shape-static expert-capacity regression: the engine pads
-    prompts to the prefill bucket, and computing the capacity ceiling
-    from the PADDED token count used to KEEP tokens the exact-length
-    oracle drops (the ROADMAP workaround pinned reduced() configs at
-    capacity_factor 8 so drops never happened). The backend now keys the
-    EXACT-length capacity into the jit cache, so this pins a prompt whose
-    routing really overflows an expert at the arch's own tight
-    capacity_factor — the oracle's trajectory changes when capacity is
-    relaxed, proving drops occur — and the engine must still match
-    token-for-token."""
+    """Serving routes dropless whatever the capacity factor: at a factor
+    so tight that training's capacity routing drops claims (its forward
+    logits change), the engine's bucket-padded prefill and fused decode
+    still reproduce the dropless static oracle token-for-token, and the
+    capacity factor changes neither the oracle nor the engine."""
     cfg, params = _setup("deepseek-v2-lite-16b")
-    assert cfg.moe.capacity_factor <= 2.0, \
-        "reduced() re-relaxed the capacity workaround"
-    prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (6,), 0,
+    tight = dataclasses.replace(
+        cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=0.25))
+    # 30 tokens: a held expert's share of the 60 claims overflows the
+    # tight ceiling, and the prompt pads to a 32-token bucket
+    prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (30,), 0,
                                            cfg.vocab_size), np.int32)
+    api = get_model(cfg)
+    batch = {"tokens": jnp.asarray(prompt[None])}
+    dropped = api.forward(tight, params, batch)
+    assert not np.allclose(np.asarray(dropped),
+                           np.asarray(api.forward(cfg, params, batch))), \
+        "capacity routing drops nothing: the differential is vacuous"
     want = _static_oracle(cfg, params, prompt, 8)
-    relaxed = dataclasses.replace(
-        cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
-    assert _static_oracle(relaxed, params, prompt, 8) != want, \
-        "prompt does not overflow an expert: the differential is vacuous"
-    got, rep = _engine_tokens(cfg, params, prompt, 8)
+    assert _static_oracle(tight, params, prompt, 8) == want
+    got, rep = _engine_tokens(tight, params, prompt, 8)
     assert got == want
     assert rep.prefill_tokens > len(prompt), \
         "prefill was not bucket-padded: the padded-ceiling path is idle"
@@ -186,16 +186,10 @@ def test_latent_engine_matches_oracle_at_tight_capacity():
 
 def test_latent_preemption_replays_routing():
     """Preempting a routed (MoE) request must not change its tokens: the
-    re-prefill replays the first prefill's recorded expert-drop
-    population, so the trajectory stays token-for-token equal to the
-    no-preemption run even at tight capacity_factor — re-deriving the
-    drops at the longer re-prefill length would keep different tokens
-    (the ROADMAP correctness carry-over)."""
+    re-prefill of prompt + generated tokens at a longer length routes
+    dropless, as the first prefill and every decode step did, so the
+    trajectory stays token-for-token equal to the no-preemption run."""
     cfg, params = _setup("deepseek-v2-lite-16b")
-    assert cfg.moe.capacity_factor <= 2.0, \
-        "reduced() re-relaxed the capacity workaround"
-    # the PRNGKey(1) prompt genuinely overflows an expert (pinned by
-    # test_latent_engine_matches_oracle_at_tight_capacity)
     prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (6,), 0,
                                            cfg.vocab_size), np.int32)
 
