@@ -109,9 +109,10 @@ def _paged_attention_oracle_err() -> float:
     rng = np.random.default_rng(0)
     B, H, KV, dh, P, page, M = 4, 8, 2, 32, 12, 8, 4
     q = jnp.asarray(rng.standard_normal((B, H, dh)), jnp.float32)
-    # pools in kernel layout (KV, P, page, dh); oracle takes model layout
-    kp = jnp.asarray(rng.standard_normal((KV, P, page, dh)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((KV, P, page, dh)), jnp.float32)
+    # two layers' pools in kernel layout (L, KV, P, page, dh), layer 1 read;
+    # oracle takes that layer in model layout
+    kp = jnp.asarray(rng.standard_normal((2, KV, P, page, dh)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((2, KV, P, page, dh)), jnp.float32)
     pt = np.zeros((B, M), np.int32)
     lengths = np.array([5, 8, 27, 0], np.int32)
     free = iter(range(1, P))
@@ -119,10 +120,11 @@ def _paged_attention_oracle_err() -> float:
         for i in range(-(-int(lengths[b]) // page)):
             pt[b, i] = next(free)
     want = ref.paged_decode_attention(
-        q, jnp.transpose(kp, (1, 2, 0, 3)), jnp.transpose(vp, (1, 2, 0, 3)),
-        jnp.asarray(pt), jnp.asarray(lengths))
+        q, jnp.transpose(kp[1], (1, 2, 0, 3)),
+        jnp.transpose(vp[1], (1, 2, 0, 3)), jnp.asarray(pt),
+        jnp.asarray(lengths))
     got = ops.paged_decode_attention(q, kp, vp, jnp.asarray(pt),
-                                    jnp.asarray(lengths), impl="interpret")
+                                    jnp.asarray(lengths), 1, impl="interpret")
     return float(np.abs(np.asarray(got) - np.asarray(want)).max())
 
 

@@ -68,7 +68,7 @@ def device_info() -> dict:
 def check_kernel(cfg, key) -> float:
     """Largest |kernel - reference| of one paged decode-attention call at
     the widths of ``cfg``: 8 slots, pages of 16 rows, 32 table columns,
-    random live lengths up to 512."""
+    random live lengths up to 512, the second layer of two-layer pools."""
     from repro.kernels import ops
 
     b, m = SLOTS, 32
@@ -76,11 +76,11 @@ def check_kernel(cfg, key) -> float:
     n_pages = 1 + b * m                          # page 0 is the trash page
     kq, kk, kv_, kp, kl = jax.random.split(key, 5)
     q = jax.random.normal(kq, (b, h, dh), jnp.bfloat16)
-    k_pages = jax.random.normal(kk, (kv, n_pages, PAGE, dh), jnp.bfloat16)
-    v_pages = jax.random.normal(kv_, (kv, n_pages, PAGE, dh), jnp.bfloat16)
+    k_pages = jax.random.normal(kk, (2, kv, n_pages, PAGE, dh), jnp.bfloat16)
+    v_pages = jax.random.normal(kv_, (2, kv, n_pages, PAGE, dh), jnp.bfloat16)
     table = (1 + jax.random.permutation(kp, b * m)).reshape(b, m)
     lengths = jax.random.randint(kl, (b,), 1, m * PAGE + 1)
-    args = (q, k_pages, v_pages, table.astype(jnp.int32), lengths)
+    args = (q, k_pages, v_pages, table.astype(jnp.int32), lengths, 1)
     got = ops.paged_decode_attention(*args)
     with jax.default_matmul_precision("highest"):
         want = ops.paged_decode_attention(*args, impl="ref")

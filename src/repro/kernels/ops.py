@@ -83,28 +83,46 @@ def decode_attention(q, k, v, lengths, *, scale=None, impl: str = "auto",
     return out.reshape(B, H, dh)
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
-                           scale=None, impl: str = "auto"):
+def _paged_ref(impl: str, dh: int) -> bool:
+    """The paged ops take the reference off the TPU, or where the kernels
+    cannot run: they copy pages out of HBM, and Mosaic cannot slice a row
+    narrower than a lane tile there, so dh must be a multiple of 128
+    (every served configuration's is)."""
+    return impl == "ref" or (impl == "auto" and (not _on_tpu() or dh % 128))
+
+
+def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, layer,
+                           *, scale=None, impl: str = "auto"):
     """Paged decode attention: q (B, H, dh) model layout; k/v_pages
-    (KV, P, page, dh) *kernel* layout (models.layers.paged_cache_init
-    stores pools head-major precisely so the decode hot loop pays no
-    pool-wide relayout here); page_table (B, M) int32; lengths (B,).
-    On TPU the kernel needs dh a multiple of 128 (it copies pages out of
-    HBM, and Mosaic cannot slice a row narrower than a lane tile there);
-    narrower heads, which no served configuration has, take the reference."""
+    (L, KV, P, page, dh), every layer's pool in the *kernel* layout
+    (models.layers.paged_cache_init), read at pool layer ``layer`` (scalar
+    int32) in place; page_table (B, M) int32; lengths (B,)."""
     B, H, dh = q.shape
-    KV = k_pages.shape[0]
-    if impl == "ref" or (impl == "auto" and (not _on_tpu() or dh % 128)):
-        kt = jnp.transpose(k_pages, (1, 2, 0, 3))  # (P, page, KV, dh)
-        vt = jnp.transpose(v_pages, (1, 2, 0, 3))
+    KV = k_pages.shape[1]
+    if _paged_ref(impl, dh):
+        kt = jnp.transpose(k_pages[layer], (1, 2, 0, 3))  # (P, page, KV, dh)
+        vt = jnp.transpose(v_pages[layer], (1, 2, 0, 3))
         return ref.paged_decode_attention(q, kt, vt, page_table, lengths,
                                           scale=scale)
-    interpret = impl == "interpret"
     qt = q.reshape(B, KV, H // KV, dh)
     out = _dec.paged_decode_attention(qt, k_pages, v_pages, page_table,
-                                      lengths, scale=scale,
-                                      interpret=interpret)
+                                      lengths, layer, scale=scale,
+                                      interpret=impl == "interpret")
     return out.reshape(B, H, dh)
+
+
+def paged_kv_append(k_pages, v_pages, k, v, layer, page_ids, offsets, *,
+                    impl: str = "auto"):
+    """Write one token per slot into the stacked pools: k/v (B, KV, dh)
+    into row ``offsets[b]`` of page ``page_ids[b]`` of layer ``layer``.
+    Returns the updated (k_pages, v_pages). Where paged_decode_attention
+    runs its kernel this is the in-place kernel; elsewhere an XLA scatter
+    with the same semantics."""
+    if _paged_ref(impl, k.shape[-1]):
+        return (ref.paged_kv_append(k_pages, k, layer, page_ids, offsets),
+                ref.paged_kv_append(v_pages, v, layer, page_ids, offsets))
+    return _dec.paged_kv_append(k_pages, v_pages, k, v, layer, page_ids,
+                                offsets, interpret=impl == "interpret")
 
 
 # --- grouped MoE GEMM --------------------------------------------------------------

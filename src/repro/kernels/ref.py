@@ -99,6 +99,14 @@ def decode_attention(q, k, v, lengths, *, scale=None):
     return out.reshape(B, H, dh).astype(q.dtype)
 
 
+def paged_kv_append(pages, new, layer, page_ids, offsets):
+    """pages (L, KV, P, page, dh); new (B, KV, dh): row ``offsets[b]`` of
+    page ``page_ids[b]`` of layer ``layer`` takes new[b] (an XLA scatter)."""
+    heads = jnp.arange(pages.shape[1])[None, :]
+    return pages.at[layer, heads, page_ids[:, None], offsets[:, None]].set(
+        new.astype(pages.dtype))
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            scale=None):
     """q: (B, H, dh); k/v_pages: (P, page, KV, dh); page_table: (B, M).

@@ -119,10 +119,14 @@ def paged_cache_init(num_layers: int, num_pages: int, page_size: int,
                      kv_heads: int, head_dim: int, dtype=COMPUTE_DTYPE):
     """(k_pages, v_pages), each (L, KV, P, page, dh).
 
-    Pools live in the *kernel* layout (head-major) so the decode hot loop
-    hands them to paged_decode_attention without relayout — a pool-wide
-    transpose per layer per step would cost O(pool bytes) HBM traffic,
-    defeating the touch-only-owned-pages design."""
+    Pools live in the *kernel* layout (head-major). The dense decode step
+    carries them whole through its layer scan and both paged kernels
+    (kernels.ops.paged_kv_append, paged_decode_attention) write and read
+    them in place, so they cross a step without relayout. Slicing a layer
+    out for ``paged_cache_append`` beside the kernel breaks this: the
+    compiler then keeps the pool token-major for the scatter and relays it
+    out around each kernel call, O(pool bytes) of HBM traffic per layer
+    and step."""
     shape = (num_layers, kv_heads, num_pages, page_size, head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 

@@ -362,11 +362,12 @@ def paged_prefill_shared(cfg, params, state: PagedDecodeState, batch,
     return last, (k.astype(L.COMPUTE_DTYPE), v.astype(L.COMPUTE_DTYPE))
 
 
-def _paged_block(cfg, p, x, batch, k_pages, v_pages, page_table,
+def _paged_block(cfg, p, x, batch, k_pages, v_pages, layer, page_table,
                  page_ids, offsets, attn_lengths, constrain=None):
-    """One decoder block over a paged cache, S == 1. k/v_pages: (KV, P,
-    page, dh) for this layer; returns (y, k_pages, v_pages) with the new
-    token appended at (page_ids, offsets)."""
+    """One decoder block over a paged cache, S == 1. k/v_pages: every
+    layer's (L, KV, P, page, dh) pool, read and written at ``layer`` in
+    place; returns (y, k_pages, v_pages) with the new token appended at
+    (page_ids, offsets)."""
     from ..kernels import ops as kops
 
     _, norm = L.make_norm(cfg)
@@ -387,10 +388,10 @@ def _paged_block(cfg, p, x, batch, k_pages, v_pages, page_table,
     q = _rope(cfg, q, batch)
     k = _rope(cfg, k, batch)
 
-    k_pages = L.paged_cache_append(k_pages, k[:, 0], page_ids, offsets)
-    v_pages = L.paged_cache_append(v_pages, v[:, 0], page_ids, offsets)
+    k_pages, v_pages = kops.paged_kv_append(k_pages, v_pages, k[:, 0],
+                                            v[:, 0], layer, page_ids, offsets)
     attn = kops.paged_decode_attention(q[:, 0], k_pages, v_pages,
-                                       page_table, attn_lengths)
+                                       page_table, attn_lengths, layer)
     if constrain is not None:
         attn = constrain(attn[:, None])[:, 0]
     y = x + (attn.reshape(B, 1, cfg.q_dim)
@@ -429,15 +430,18 @@ def paged_decode_step(cfg, params, state: PagedDecodeState, tokens,
     offsets = jnp.where(active, lengths % page, 0)
     attn_lengths = jnp.where(active, lengths + 1, 0)        # incl. new token
 
-    def body(carry, xs):
-        p, kp, vp = xs
-        y, kp, vp = _paged_block(cfg, p, carry, batch, kp, vp, page_table,
-                                 page_ids, offsets, attn_lengths,
+    def body(carry, p):
+        # the pools ride in the carry, so each layer's append and read land
+        # in place; as xs/ys the scan would slice and restack them
+        x, kp, vp, layer = carry
+        y, kp, vp = _paged_block(cfg, p, x, batch, kp, vp, layer,
+                                 page_table, page_ids, offsets, attn_lengths,
                                  constrain=constrain)
-        return y, (kp, vp)
+        return (y, kp, vp, layer + 1), None
 
-    x, (k_pages, v_pages) = lax.scan(
-        body, x, (params["blocks"], state.k_pages, state.v_pages))
+    (x, k_pages, v_pages, _), _ = lax.scan(
+        body, (x, state.k_pages, state.v_pages, jnp.zeros((), jnp.int32)),
+        params["blocks"])
     logits = _head(cfg, params, x)[:, 0]
     return logits, PagedDecodeState(k_pages=k_pages, v_pages=v_pages)
 

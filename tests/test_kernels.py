@@ -4,6 +4,7 @@ Sweeps shapes/dtypes per the task spec. bf16 tolerances are loose (the
 kernels accumulate in f32 but inputs are quantized to bf16).
 """
 
+import functools
 import importlib
 
 import jax
@@ -217,13 +218,19 @@ def test_ops_moe_ffn():
 
 # --- paged decode attention ----------------------------------------------------------
 
+# every paged test reads or writes a stacked pool at a layer that is not 0,
+# so a kernel that ignores the layer index reads another layer's pages
+LAYERS, LAYER = 3, 1
+
+
 def _paged_case(key, B, KV, dh, P, page, M, lens, dtype=jnp.float32,
                 shuffled=False):
-    """k/v pools in kernel layout (KV, P, page, dh) + table + lengths; pages
-    owned in id order, or (``shuffled``) out of order and non-contiguous."""
+    """k/v pools in kernel layout, stacked over LAYERS layers (LAYERS, KV,
+    P, page, dh) + table + lengths; pages owned in id order, or
+    (``shuffled``) out of order and non-contiguous."""
     ks = jax.random.split(key, 3)
-    kp = rand(ks[0], (KV, P, page, dh), dtype)
-    vp = rand(ks[1], (KV, P, page, dh), dtype)
+    kp = rand(ks[0], (LAYERS, KV, P, page, dh), dtype)
+    vp = rand(ks[1], (LAYERS, KV, P, page, dh), dtype)
     pt = np.zeros((B, M), np.int32)
     ids = np.arange(1, P)
     free = iter(np.random.default_rng(0).permutation(ids) if shuffled
@@ -234,8 +241,9 @@ def _paged_case(key, B, KV, dh, P, page, M, lens, dtype=jnp.float32,
     return kp, vp, jnp.asarray(pt), jnp.asarray(np.asarray(lens, np.int32))
 
 
-def _to_model_layout(pages):
-    return jnp.transpose(pages, (1, 2, 0, 3))      # (P, page, KV, dh)
+def _to_model_layout(pages, layer=LAYER):
+    """One layer of a stacked pool in the reference's (P, page, KV, dh)."""
+    return jnp.transpose(pages[layer], (1, 2, 0, 3))
 
 
 @pytest.mark.parametrize("B,KV,G,dh,P,page,M,lens", [
@@ -259,7 +267,7 @@ def test_paged_decode_attention_oracle(B, KV, G, dh, P, page, M, lens):
     q = rand(jax.random.PRNGKey(0), (B, H, dh), jnp.float32)
     kp, vp, pt, lengths = _paged_case(jax.random.PRNGKey(1), B, KV, dh, P,
                                       page, M, lens)
-    got = ops.paged_decode_attention(q, kp, vp, pt, lengths,
+    got = ops.paged_decode_attention(q, kp, vp, pt, lengths, LAYER,
                                      impl="interpret")
     want = ref.paged_decode_attention(q, _to_model_layout(kp),
                                       _to_model_layout(vp), pt, lengths)
@@ -289,7 +297,7 @@ def test_paged_matches_dense_decode_attention():
                                       page, M, lens)
     k = _to_model_layout(kp)[pt].reshape(B, M * page, KV, dh)
     v = _to_model_layout(vp)[pt].reshape(B, M * page, KV, dh)
-    got = ops.paged_decode_attention(q, kp, vp, pt, lengths,
+    got = ops.paged_decode_attention(q, kp, vp, pt, lengths, LAYER,
                                      impl="interpret")
     want = ref.decode_attention(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -330,7 +338,7 @@ def test_paged_decode_attention_edge_shapes(B, KV, G, dh, P, page, M, lens,
     q = rand(jax.random.PRNGKey(6), (B, H, dh), jnp.float32)
     kp, vp, pt, lengths = _paged_case(jax.random.PRNGKey(7), B, KV, dh, P,
                                       page, M, lens, shuffled=shuffled)
-    got = ops.paged_decode_attention(q, kp, vp, pt, lengths,
+    got = ops.paged_decode_attention(q, kp, vp, pt, lengths, LAYER,
                                      impl="interpret")
     want = ref.paged_decode_attention(q, _to_model_layout(kp),
                                       _to_model_layout(vp), pt, lengths)
@@ -350,15 +358,113 @@ def test_paged_attention_ignores_foreign_pages():
     kp, vp, pt, lengths = _paged_case(jax.random.PRNGKey(5), B, KV, dh, P,
                                       page, M, [13, 24])
     base = np.asarray(ops.paged_decode_attention(q, kp, vp, pt, lengths,
-                                                 impl="ref"))
+                                                 LAYER, impl="ref"))
     owned0 = set(np.asarray(pt)[0, :2].tolist())
     foreign = [p for p in range(P) if p not in owned0]
-    kp2 = kp.at[:, jnp.asarray(foreign)].set(99.0)
-    vp2 = vp.at[:, jnp.asarray(foreign)].set(-99.0)
+    kp2 = kp.at[:, :, jnp.asarray(foreign)].set(99.0)
+    vp2 = vp.at[:, :, jnp.asarray(foreign)].set(-99.0)
     poked = np.asarray(ops.paged_decode_attention(q, kp2, vp2, pt, lengths,
-                                                  impl="ref"))
+                                                  LAYER, impl="ref"))
     np.testing.assert_array_equal(base[0], poked[0])
     assert np.abs(base[1] - poked[1]).max() > 1.0   # seq 1 did change
+
+
+# --- in-place append into the paged pools ---------------------------------------
+
+@pytest.mark.parametrize("layer", [0, LAYERS - 1],
+                         ids=["first-layer", "last-layer"])
+@pytest.mark.parametrize("KV,page,dh,P,page_ids,offsets,dtype", [
+    pytest.param(2, 8, 128, 10, [3, 5, 1], [0, 0, 0], jnp.float32,
+                 id="offset-0"),
+    pytest.param(2, 8, 128, 10, [3, 5, 1], [3, 4, 5], jnp.float32,
+                 id="mid-page"),
+    pytest.param(2, 8, 128, 10, [3, 5, 1], [7, 7, 7], jnp.bfloat16,
+                 id="last-row"),
+    # inactive slots all append at row 0 of the trash page
+    pytest.param(2, 8, 128, 10, [0, 4, 0, 6, 0], [0, 2, 0, 7, 0],
+                 jnp.bfloat16, id="trash-collisions"),
+    # 128 KiB pages: 16 slots fit a step's VMEM, so 20 take two steps of 10
+    pytest.param(16, 16, 128, 24, list(range(20)), [i % 16 for i in range(20)],
+                 jnp.float32, id="two-grid-steps"),
+])
+def test_paged_kv_append(KV, page, dh, P, page_ids, offsets, dtype, layer):
+    """The in-place append matches the XLA scatter bit for bit, outside the
+    trash page; there only the collided rows change, each to one of the
+    rows written to it; every page not appended to is left as it was."""
+    B = len(page_ids)
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    kp = rand(ks[0], (LAYERS, KV, P, page, dh), dtype)
+    vp = rand(ks[1], (LAYERS, KV, P, page, dh), dtype)
+    k = rand(ks[2], (B, KV, dh), dtype)
+    v = rand(ks[3], (B, KV, dh), dtype)
+    pids = jnp.asarray(page_ids, jnp.int32)
+    offs = jnp.asarray(offsets, jnp.int32)
+    got = ops.paged_kv_append(kp, vp, k, v, layer, pids, offs,
+                              impl="interpret")
+    want = ops.paged_kv_append(kp, vp, k, v, layer, pids, offs, impl="ref")
+    live = np.asarray(page_ids) != 0
+    untouched = np.ones((LAYERS, P), bool)
+    untouched[layer, np.asarray(page_ids)] = False
+    for pool, new, g, w in zip((kp, vp), (k, v), got, want):
+        pool, new, g, w = (np.asarray(a) for a in (pool, new, g, w))
+        np.testing.assert_array_equal(g[:, :, 1:], w[:, :, 1:])
+        np.testing.assert_array_equal(g.transpose(0, 2, 1, 3, 4)[untouched],
+                                      pool.transpose(0, 2, 1, 3, 4)[untouched])
+        trash = np.asarray(offsets)[~live]
+        rows = np.setdiff1d(np.arange(page), trash)
+        np.testing.assert_array_equal(g[:, :, 0][:, :, rows],
+                                      pool[:, :, 0][:, :, rows])
+        for r in np.unique(trash):
+            written = new[~live][np.asarray(offsets)[~live] == r]
+            assert any(np.array_equal(g[layer, :, 0, r], n) for n in written)
+
+
+@pytest.mark.parametrize("B,page_bytes,want", [
+    (20, 64 * 1024, 20),       # olmo-1b.chat: every slot in one step
+    (20, 128 * 1024, 10),      # float32 pages: two steps
+    (7, 3 * 2**20, 1),         # a page over the budget: one slot a step
+])
+def test_append_slots(B, page_bytes, want):
+    """Slots per append step: the largest divisor of B whose pages of one
+    pool take at most 2 MiB of VMEM."""
+    assert _dec._append_slots(B, page_bytes) == want
+
+
+def test_paged_decode_step_kernels_match_reference(monkeypatch):
+    """The dense paged decode step with both paged kernels (interpret
+    mode) against the same step on the reference ops: the pools it carries
+    through the layer scan, each layer's append and read at its own layer
+    index, give the same logits and the same pools."""
+    from repro.configs import get_config
+    from repro.models import transformer as T
+
+    cfg = get_config("olmo-1b").reduced()
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    B, page, M, P = 3, 8, 4, 12
+    st = T.init_paged_decode_state(cfg, P, page)
+    st = T.PagedDecodeState(
+        *(rand(k, st.k_pages.shape, st.k_pages.dtype)
+          for k in jax.random.split(jax.random.PRNGKey(1), 2)))
+    pt = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [0, 0, 0, 0]], jnp.int32)
+    lengths = jnp.asarray([17, 8, 0], jnp.int32)
+    active = jnp.asarray([True, True, False])
+    tokens = jnp.asarray([5, 7, 0], jnp.int32)
+    assert cfg.num_layers > 1
+
+    def run(impl):
+        with monkeypatch.context() as mp:
+            for name in ("paged_kv_append", "paged_decode_attention"):
+                mp.setattr(ops, name, functools.partial(getattr(ops, name),
+                                                        impl=impl))
+            return T.paged_decode_step(cfg, params, st, tokens, pt, lengths,
+                                       active)
+
+    (lg, got), (lw, want) = run("interpret"), run("ref")
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(lw), **TOL[
+        jnp.bfloat16])
+    for g, w in ((got.k_pages, want.k_pages), (got.v_pages, want.v_pages)):
+        np.testing.assert_array_equal(np.asarray(g)[:, :, 1:],
+                                      np.asarray(w)[:, :, 1:])
 
 
 # --- packed canvas fused epilogue ----------------------------------------------------
