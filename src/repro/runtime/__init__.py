@@ -9,8 +9,7 @@ from .engine import (ENGINE_FAMILIES, Engine, EngineConfig, EngineReport,
                      HybridBackend, LatentBackend, PagedTransformerBackend,
                      PoolEngineConfig, PooledEngine, PooledReport,
                      RecurrentBackend, engine_backend, make_batch_sampler,
-                     make_sampler, resolve_backend, run_static,
-                     vlm_extras_fn)
+                     resolve_backend, run_static, vlm_extras_fn)
 from .fault_tolerance import (TRANSIENT_DEFAULT, Backoff, ElasticConfig,
                               FaultEvent, FaultSchedule, RunReport,
                               StepTimeout, StragglerDetector,
@@ -22,8 +21,8 @@ from .model_pool import (ModelEntry, ModelPool, PoolConfig, PoolError,
                          PoolPlan, calibrated_reload_bytes_per_step,
                          model_weight_bytes)
 from .prefix_index import PrefixIndex
-from .scheduler import (MultiQueueScheduler, Request, Scheduler,
-                        diurnal_trace, multi_tenant_trace, poisson_trace,
+from .scheduler import (Request, Scheduler, diurnal_trace,
+                        multi_tenant_trace, poisson_trace,
                         shared_prefix_trace, shifting_mix_trace)
 
 __all__ = ["ArenaConfig", "DeviceArena",
@@ -31,14 +30,14 @@ __all__ = ["ArenaConfig", "DeviceArena",
            "PagedTransformerBackend", "RecurrentBackend", "HybridBackend",
            "LatentBackend", "engine_backend", "resolve_backend",
            "PooledEngine", "PoolEngineConfig", "PooledReport",
-           "run_static", "make_sampler", "make_batch_sampler",
+           "run_static", "make_batch_sampler",
            "vlm_extras_fn", "DeviceLoopState",
            "PageAllocator", "PagerConfig", "TRASH_PAGE", "NEUTRAL_OWNER",
            "partition_pages", "PrefixIndex",
            "ModelPool", "ModelEntry", "PoolConfig", "PoolError", "PoolPlan",
            "model_weight_bytes", "calibrated_reload_bytes_per_step",
            "DmaChannel", "DeviceDmaChannel", "WeightStream",
-           "Request", "Scheduler", "MultiQueueScheduler",
+           "Request", "Scheduler",
            "poisson_trace", "multi_tenant_trace", "shifting_mix_trace",
            "diurnal_trace", "shared_prefix_trace",
            "ElasticConfig", "RunReport", "StepTimeout",

@@ -46,7 +46,7 @@ from .device_state import DeviceLoopState
 from .kv_pager import PagerConfig, TRASH_PAGE
 from .model_pool import ModelPool
 from .prefix_index import PrefixIndex
-from .scheduler import MultiQueueScheduler, Request, Scheduler
+from .scheduler import Request, Scheduler
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,8 +90,8 @@ class EngineConfig:
 
 def make_batch_sampler(rng: np.random.Generator, greedy: bool,
                        temperature: float):
-    """Shared host-side batch sampler (engine, pooled engine and static
-    baseline all draw through this one helper). Greedy argmaxes the
+    """Shared host-side batch sampler (the engine loop and the static
+    baseline both draw through this one helper). Greedy argmaxes the
     whole (N, V) block at once; the temperature path draws ONE uniform
     per row and inverts the softmax CDF, so a seeded run is
     deterministic and the per-slot Python sampling loop is gone from
@@ -112,16 +112,6 @@ def make_batch_sampler(rng: np.random.Generator, greedy: bool,
         return np.minimum((cdf < u[:, None]).sum(axis=-1),
                           rows.shape[-1] - 1)
     return sample_batch
-
-
-def make_sampler(rng: np.random.Generator, greedy: bool,
-                 temperature: float):
-    """Single-row view of make_batch_sampler (prefill samples one row)."""
-    sample_batch = make_batch_sampler(rng, greedy, temperature)
-
-    def sample(logits_row: np.ndarray) -> int:
-        return int(sample_batch(logits_row[None])[0])
-    return sample
 
 
 def vlm_extras_fn(cfg, num_patches: int = 4):
@@ -149,6 +139,8 @@ class EngineReport:
     cow_copies: int = 0                # divergence-write page copies
     prefill_tokens_saved: int = 0      # bucketed tokens NOT recomputed
     peak_demand_pages: int = 0         # live minus index-only cache
+    peak_live_page_bytes: int = 0      # tenants' page sizes differ
+    peak_demand_page_bytes: int = 0    # live minus index-only, in bytes
     page_bytes: int = 0                # 0 -> non-paged backend
     slot_state_bytes: int = 0          # per-slot non-paged state (hybrid)
     cache_bytes_alloc: int = 0         # full backing allocation
@@ -193,14 +185,15 @@ class EngineReport:
 
     @property
     def kv_bytes_peak(self) -> int:
-        """Peak cache bytes holding *live* tokens (paged) or the full
-        dense allocation (static / recurrent). A paged backend with
+        """Peak cache bytes holding *live* tokens (paged; each tenant's
+        pages at its own size, since an MLA latent page is far smaller
+        than a dense KV page) or the full dense allocation (static /
+        recurrent). A paged backend with
         per-slot recurrent state (hybrid) adds that constant term so the
         comparison against the static path — whose _state_bytes includes
         the same conv/LRU arrays — stays symmetric."""
         if self.page_bytes:
-            return (self.peak_live_pages * self.page_bytes
-                    + self.slot_state_bytes)
+            return self.peak_live_page_bytes + self.slot_state_bytes
         return self.cache_bytes_alloc
 
     @property
@@ -212,8 +205,7 @@ class EngineReport:
         where demand == live and the metric degrades to kv_bytes_peak.
         """
         if self.page_bytes:
-            return (self.peak_demand_pages * self.page_bytes
-                    + self.slot_state_bytes)
+            return self.peak_demand_page_bytes + self.slot_state_bytes
         return self.cache_bytes_alloc
 
     def latency_percentiles(self, qs=(50, 95)) -> dict[str, float]:
@@ -776,391 +768,575 @@ class _PrefixSharing:
 # --- engine --------------------------------------------------------------------
 
 
+class _Tenant:
+    """One model's state in the engine loop: its backend, its pager
+    geometry (admission feasibility, page boundaries), and for the run
+    its page allocator (an arena lease) and prefix sharer. ``decodes`` is
+    the policy's word for this step: False holds its slots back."""
+
+    __slots__ = ("model_id", "backend", "pgr", "alloc", "sharer",
+                 "decodes", "tokens")
+
+    def __init__(self, model_id: str, backend, pgr: PagerConfig):
+        self.model_id, self.backend, self.pgr = model_id, backend, pgr
+        self.alloc = self.sharer = None
+        self.decodes = True
+        self.tokens = 0                # tokens served this run
+
+
 class Engine:
-    """Host-driven continuous-batching loop around a jitted decode step."""
+    """Continuous batching over a table of tenants.
+
+    ``step_once`` is the one engine step: arrivals and admission, page
+    growth, the safe horizon, the decode dispatch and delivery.
+    ``Engine(cfg, params, ecfg)`` serves one tenant over the whole page
+    budget. ``PooledEngine`` builds its tenants from a pool plan and
+    decides, at the ``_policy_*`` points, which tenants admit and decode
+    and how far a horizon may reach; here those points return at once.
+    ``run`` is ``start``, ``step_once`` until nothing can progress, and
+    ``finish_run``; a caller may drive the three itself and ``inject``
+    work between steps (the fleet tier does)."""
 
     def __init__(self, cfg, params, ecfg: EngineConfig | None = None):
         self.cfg = cfg
         self.ecfg = ecfg or EngineConfig()
         self.backend = resolve_backend(cfg)(cfg, params, self.ecfg)
-        self._home = _home_sharding(params)
-        if self._home is not None:
-            self.backend.state = jax.device_put(self.backend.state,
-                                                self._home)
+        home = _home_sharding(params)
+        if home is not None:
+            self.backend.state = jax.device_put(self.backend.state, home)
+        self.arena = None
+        if self.backend.paged:
+            # one lease spanning the whole page budget
+            self.arena = DeviceArena(ArenaConfig(kv_pages=self.ecfg.num_pages),
+                                     {"default": 1.0})
+            self.arena.register_page_bytes("default", self.backend.page_bytes)
+        self._init_loop([_Tenant("default", self.backend, self.ecfg.pager)],
+                        home)
+
+    def _init_loop(self, tenants: list[_Tenant], home) -> None:
+        self._tenants = tenants
+        self._paged = [t for t in tenants if t.backend.paged]
+        self._home = home
         self.rng = np.random.default_rng(self.ecfg.seed)
         self._sample_batch = make_batch_sampler(
             self.rng, self.ecfg.greedy, self.ecfg.temperature)
-        self._sample = make_sampler(self.rng, self.ecfg.greedy,
-                                    self.ecfg.temperature)
         # greedy sampling is pure argmax, so it can run on device inside
         # the fused horizon; the host RNG's temperature draw cannot
         self._fused = self.ecfg.greedy and self.ecfg.horizon > 1
 
-    # -- main loop ---------------------------------------------------------
+    # -- policy points: the pooled engine's decisions ----------------------
+
+    def _tenant_of(self, req: Request) -> _Tenant:
+        return self._tenants[0]
+
+    def _policy_step(self) -> list[str] | None:
+        """Which models' queues may admit this step (None: every one)."""
+        return None
+
+    def _policy_waits(self, req: Request, t: _Tenant, need: int,
+                      fits: bool) -> bool:
+        """Whether the admission scan stops at this head, which needs
+        ``need`` pages and ``fits`` or not: strict FCFS waits."""
+        return not fits
+
+    def _policy_starved(self, t: _Tenant) -> None:
+        """A slot of ``t`` found no free page to grow into."""
+
+    def _policy_horizon(self) -> int:
+        """The policy's cap on this step's horizon."""
+        return self.ecfg.horizon
+
+    def _policy_end(self, h: int, decoded: list[_Tenant]) -> None:
+        """After the step's ``h`` decode steps, run by ``decoded``."""
+
+    def _new_report(self) -> EngineReport:
+        return EngineReport(name=f"engine/{self.cfg.name}",
+                            **self._report_base())
+
+    def _report_base(self) -> dict:
+        bs = [t.backend for t in self._tenants]
+        return dict(num_slots=self.ecfg.num_slots,
+                    page_bytes=max((t.backend.page_bytes
+                                    for t in self._paged), default=0),
+                    slot_state_bytes=sum(b.slot_state_bytes for b in bs),
+                    cache_bytes_alloc=sum(_state_bytes(b.state) for b in bs))
+
+    # -- the loop ----------------------------------------------------------
+
+    def start(self, requests: list[Request]) -> "Engine":
+        e = self.ecfg
+        B, M = e.num_slots, e.max_pages_per_seq
+        self._sched = Scheduler(requests)
+        if self.arena is not None:
+            # a fresh run starts from the initial leases' fresh allocators
+            self.arena.reset_runtime()
+        for t in self._tenants:
+            t.alloc = (self.arena.allocator(t.model_id)
+                       if t.backend.paged else None)
+            # one prefix index per eligible tenant: page ids are tenant-
+            # local and token-id equality only implies KV equality within
+            # one model
+            t.sharer = (_PrefixSharing(t.pgr)
+                        if t.alloc is not None and e.prefix_sharing
+                        and t.backend.prefix_sharing else None)
+            t.tokens = 0
+        self._slots: list[Request | None] = [None] * B
+        self._owner: list[_Tenant | None] = [None] * B
+        self._page_table = np.zeros((B, M), np.int32)
+        self._lengths = np.zeros((B,), np.int32)
+        self._pending = np.zeros((B,), np.int32)     # next decode input
+        self._remaining = np.zeros((B,), np.int32)   # token budget left
+        # device twins of the four loop arrays, shared by every tenant
+        # (fused dispatches chain through them), and the traffic ledger
+        # the per-step path shares
+        self._ds = DeviceLoopState(B, M, self._home)
+        self._rep = self._new_report()
+        self._t_run = time.monotonic()
+        self.step = 0
+        return self
 
     def run(self, requests: list[Request]) -> EngineReport:
-        e, pgr = self.ecfg, self.ecfg.pager
-        B, M, page = e.num_slots, pgr.max_pages_per_seq, pgr.page_size
-        paged = self.backend.paged
-        sched = Scheduler(requests)
-        # single-tenant arena: one lease spanning the whole page budget
-        # (the same allocator path the pooled engine leases per tenant)
-        arena = DeviceArena(ArenaConfig(kv_pages=e.num_pages),
-                            {"default": 1.0}) if paged else None
-        alloc = arena.allocator("default") if paged else None
-        if paged:
-            arena.register_page_bytes("default", self.backend.page_bytes)
-        sharer = _PrefixSharing(pgr) if (
-            paged and e.prefix_sharing
-            and getattr(self.backend, "prefix_sharing", False)) else None
+        self.start(requests)
+        while self.step_once():
+            pass
+        return self.finish_run()
 
-        slots: list[Request | None] = [None] * B
-        page_table = np.zeros((B, M), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        pending = np.zeros((B,), np.int32)      # next decode input token
-        remaining = np.zeros((B,), np.int32)    # token budget left
-        # device twins of the four loop arrays + the traffic ledger the
-        # per-step fallback shares (so both paths report comparably)
-        ds = DeviceLoopState(B, M, self._home)
-
-        page_bytes = self.backend.page_bytes
-        expert_rows = self.backend.expert_rows_per_step
-
-        def count_latent(h: int, claims) -> None:
-            """The latent path's counters for a dispatch of ``h`` steps,
-            taken before the lengths advance: live claims on held experts
-            against the expert rows computed, and the latent rows live
-            (each slot attends over its context and the new token) against
-            the rows the whole page table's gather reads, per layer."""
-            tracing.ROUTES.add(int(claims), h * expert_rows)
-            live = (int(lengths[active].sum()) * h
-                    + len(active) * h * (h + 1) // 2)
-            tracing.LATENT_READS.add(live, B * M * page * h)
-
-        rep = EngineReport(
-            name=f"engine/{self.cfg.name}", num_slots=B,
-            page_bytes=page_bytes,
-            slot_state_bytes=self.backend.slot_state_bytes,
-            cache_bytes_alloc=_state_bytes(self.backend.state))
-        t_run = time.monotonic()
-        step = 0
-
-        def clear_slot(s: int) -> None:
-            req = slots[s]
-            if sharer is not None:
-                # index the pages this request completed (incl. during
-                # decode) BEFORE dropping its refs: the neutral refs
-                # keep the prefix warm for later prompts / re-admission
-                sharer.record(alloc, req.context_tokens, lengths[s],
-                              page_table[s])
-            slots[s] = None
-            page_table[s, :] = TRASH_PAGE
-            lengths[s] = 0
-            pending[s] = 0
-            remaining[s] = 0
-            ds.touch(s)
-            if paged:
-                alloc.free_owner(req.rid)
-            self.backend.release_slot(s)
-
-        def finish(s: int) -> None:
-            slots[s].done_step = step
-            rep.completed.append(slots[s])
-            clear_slot(s)
-
-        def preempt(s: int) -> None:
-            req = slots[s]
-            clear_slot(s)
-            sched.requeue(req)
-
-        while True:
-            with StepTraceAnnotation("engine.step", step_num=step):
-                sched.release_arrivals(step)
-
-                # -- admission into free slots -------------------------------
-                admitting = True
-                for s in range(B):
-                    # retry the same slot until it is filled (rejected or
-                    # finished-at-prefill requests must not waste the slot)
-                    while admitting and slots[s] is None:
-                        req = sched.peek_ready()
-                        if req is None:
-                            admitting = False
-                            break
-                        with TraceAnnotation("engine.admit", rid=req.rid):
-                            ctx = req.context_tokens
-                            assert len(ctx) >= 1, \
-                                "empty prompts are not admissible"
-                            if paged:
-                                rows = self.backend.admission_rows(
-                                    pgr, len(ctx))
-                                if not self.backend.can_ever_fit(
-                                        pgr, len(req.prompt),
-                                        req.max_new_tokens, len(ctx)):
-                                    # can never fit: fail fast
-                                    sched.pop_ready()
-                                    req.truncated = True
-                                    req.done_step = step
-                                    rep.completed.append(req)
-                                    continue
-                                sh_pages, sh_tokens = (
-                                    sharer.plan(req, ctx)
-                                    if sharer is not None else ([], 0))
-                                need = len(rows) - len(sh_pages)
-                                if not alloc.can_alloc(need) \
-                                        and sharer is not None:
-                                    # index-only pages are cache: reclaim them
-                                    # before making the request wait
-                                    sharer.index.evict_lru(
-                                        alloc, need - alloc.free_count,
-                                        protect=set(sh_pages))
-                                if not alloc.can_alloc(need):
-                                    # FCFS: wait for free pages
-                                    admitting = False
-                                    break
-                                sched.pop_ready()
-                                if sh_pages:
-                                    alloc.share(req.rid, sh_pages)
-                                    req.shared_pages += len(sh_pages)
-                                    rep.shared_page_hits += len(sh_pages)
-                                pages = alloc.alloc(req.rid, need)
-                                page_table[s, :] = TRASH_PAGE
-                                page_table[s, rows] = sh_pages + pages
-                                with TraceAnnotation("engine.prefill"):
-                                    if sh_tokens >= len(ctx):
-                                        # fully cached re-admission
-                                        logits = None
-                                    elif sh_tokens:
-                                        logits = self.backend.prefill_shared(
-                                            ctx, req.extras, s, pages,
-                                            sh_pages, sh_tokens)
-                                    else:
-                                        logits = self.backend.prefill(
-                                            ctx, req.extras, s, pages)
-                                bucket = e.prefill_bucket
-                                full = -(-len(ctx) // bucket) * bucket
-                                computed = 0 if sh_tokens >= len(ctx) else (
-                                    -(-(len(ctx) - sh_tokens) // bucket)
-                                    * bucket)
-                                rep.prefill_tokens += computed
-                                rep.prefill_tokens_saved += full - computed
-                                if computed:
-                                    rep.prefill_calls += 1
-                                    req.prefills += 1
-                                    tracing.PREFILLS.add(
-                                        len(ctx) - sh_tokens, computed)
-                                if sharer is not None:
-                                    sharer.record(alloc, ctx, len(ctx),
-                                                  page_table[s])
-                            else:
-                                sched.pop_ready()
-                                with TraceAnnotation("engine.prefill"):
-                                    logits = self.backend.prefill(
-                                        ctx, req.extras, s, None)
-                                rep.prefill_calls += 1
-                                rep.prefill_tokens += len(ctx)
-                                req.prefills += 1
-                                tracing.PREFILLS.add(len(ctx), len(ctx))
-                            req.admitted_step = step
-                            slots[s] = req
-                            lengths[s] = len(ctx)
-                            # re-admission after preemption
-                            if req.generated:
-                                pending[s] = req.generated[-1]
-                                remaining[s] = (req.max_new_tokens
-                                                - len(req.generated))
-                                ds.touch(s)
-                            else:
-                                assert logits is not None
-                                tok = self._sample(logits)
-                                req.generated.append(tok)
-                                pending[s] = tok
-                                remaining[s] = req.max_new_tokens - 1
-                                ds.touch(s)
-                                if req.done:
-                                    finish(s)   # slot freed: while re-admits
-
-                active = [s for s in range(B) if slots[s] is not None]
-
-                # -- page growth / CoW / preemption --------------------------
-                if paged and active:
+    def step_once(self) -> bool:
+        """Advance one engine step (a fused horizon of h decode steps
+        counts h). False when nothing can progress — every queue empty
+        and no slot occupied (``run`` stops there; the fleet keeps an idle
+        replica, since more work may be injected) — and True otherwise,
+        including an idle fast-forward to the next arrival."""
+        with StepTraceAnnotation("engine.step", step_num=self.step):
+            sched = self._sched
+            sched.release_arrivals(self.step)
+            serve = self._policy_step()
+            admitting = True
+            for s in range(self.ecfg.num_slots):
+                # retry the same slot until it is filled (rejected or
+                # finished-at-prefill requests must not waste the slot)
+                while admitting and self._slots[s] is None:
+                    admitting = self._admit(s, serve)
+            active = [s for s, r in enumerate(self._slots) if r is not None]
+            h, decoded = 1, []
+            if active:
+                if self._paged:
                     with TraceAnnotation("engine.grow"):
-                        R = self.backend.ring_rows
+                        self._grow(active)
+                if active:              # growth may have emptied it
+                    h, decoded = self._decode(active)
+            elif sched.exhausted:
+                return False
+            else:
+                nxt = sched.next_arrival()
+                if nxt is not None and nxt > self.step \
+                        and sched.peek_ready() is None:
+                    self.step = nxt     # idle: fast-forward to next arrival
+                    return True
+                # ready work is blocked (a deferred activation, a page
+                # wait, a layer stream): let time pass
+            self._policy_end(h, decoded)
+            self.step += 1
+            if self.step > self.ecfg.max_steps:
+                raise RuntimeError("engine exceeded max_steps")
+            return True
 
-                        def claim_one(s: int) -> bool:
-                            """Free one page for slot ``s``: index cache
-                            first, then victim preemption (whose pages may
-                            land in the index — evictable next iteration, so
-                            the loop still strictly shrinks live state).
-                            False if ``s`` itself was preempted."""
-                            while not alloc.can_alloc(1):
-                                if sharer is not None \
-                                        and sharer.index.evict_lru(alloc, 1):
-                                    continue
-                                victim = Scheduler.pick_victim(
-                                    [(v, slots[v]) for v in active
-                                     if slots[v] is not None], exclude=s)
-                                if victim is None or victim[0] == s:
-                                    preempt(s)
-                                    active.remove(s)
-                                    return False
-                                preempt(victim[0])
-                                active.remove(victim[0])
-                            return True
-
-                        for s in list(active):
-                            if slots[s] is None:
-                                continue
-                            if lengths[s] % page != 0:
-                                # mid-page: the next decode appends into the
-                                # current tail page — if that page is still
-                                # shared (re-admission mapped a cached tail),
-                                # copy-on-write exactly that page first
-                                if sharer is None:
-                                    continue
-                                row_i = lengths[s] // page
-                                old = int(page_table[s, row_i])
-                                if alloc.refcount(old) < 2:
-                                    continue
-                                if not claim_one(s):
-                                    continue
-                                new = alloc.alloc(slots[s].rid, 1)
-                                self.backend.copy_page(old, new[0])
-                                alloc.free_page(slots[s].rid, old)
-                                page_table[s, row_i] = new[0]
-                                ds.touch(s)
-                                slots[s].cow_copies += 1
-                                rep.cow_copies += 1
-                                continue
-                            pi = lengths[s] // page
-                            if R is None and pi >= M:   # table row full: stop
-                                slots[s].truncated = True
-                                finish(s)
-                                active.remove(s)
-                                continue
-                            row = _growth_row(self.backend, alloc,
-                                              page_table, s, pi, slots[s].rid)
-                            if not claim_one(s):
-                                continue
-                            new = alloc.alloc(slots[s].rid, 1)
-                            page_table[s, row] = new[0]
-                            ds.touch(s)
-
-                # -- decode: one fused horizon, or one per-step dispatch -----
-                if active:
-                    act = np.zeros((B,), bool)
-                    act[active] = True
-                    if self._fused:
-                        # safe horizon: no schedulable event may land inside
-                        # it, so running h steps device-side is step-for-step
-                        # identical to h per-step iterations of this loop;
-                        # ``cause`` names the cut that bound h (a later cut
-                        # takes it over only by cutting shorter)
-                        h, cause = e.horizon, "cap"
-                        nxt = sched.next_arrival()
-                        if nxt is not None and nxt - step < h:
-                            h, cause = nxt - step, "arrival"
-                        if sched.peek_ready() is not None and \
-                                any(slots[s] is None for s in range(B)):
-                            # a free slot retries admission per step
-                            h, cause = 1, "admit"
-                        fin = min(int(remaining[s]) for s in active)
-                        if fin < h:
-                            h, cause = fin, "finish"
-                        if paged:                      # growth/ring wrap
-                            edge = min(pgr.steps_to_boundary(int(lengths[s]))
-                                       for s in active)
-                            if edge < h:
-                                h, cause = edge, "page"
-                        h = max(1, h)
-                        with TraceAnnotation("engine.sync"):
-                            ds.sync(page_table, lengths, pending, remaining)
-                        tracing.DISPATCHES.add(h, len(active), cause)
-                        with TraceAnnotation("engine.decode"):
-                            out, p_d, l_d, r_d = self.backend.decode_fused(
-                                ds.pending, ds.lengths, ds.remaining,
-                                ds.table, act, h)
-                        with TraceAnnotation("engine.wait"):
-                            if expert_rows:   # claims ride with the tokens
-                                toks_h, claims = jax.device_get(
-                                    (out, self.backend.state.claims))
-                            else:
-                                toks_h = np.asarray(out)   # ONE host sync
-                        if expert_rows:
-                            count_latent(h, claims)
-                        ds.adopt(p_d, l_d, r_d)
-                        ds.count(dispatches=1, syncs=1)
-                        rep.decode_steps += h
-                        rep.slot_steps += B * h
-                        rep.useful_slot_steps += len(active) * h
-                        step += h - 1   # bookkeeping lands at horizon end
-                        lengths[active] += h
-                        remaining[active] -= h
-                        with TraceAnnotation("engine.deliver"):
-                            for s in active:
-                                req = slots[s]
-                                req.generated.extend(
-                                    int(t) for t in toks_h[:h, s])
-                                pending[s] = int(toks_h[h - 1, s])
-                                if req.done:
-                                    finish(s)
-                    else:
-                        tracing.DISPATCHES.add(1, len(active), "unfused")
-                        with TraceAnnotation("engine.decode"):
-                            logits = self.backend.decode(
-                                pending, page_table, lengths, act)
-                        with TraceAnnotation("engine.wait"):
-                            if expert_rows:
-                                logits, claims = jax.device_get(
-                                    (logits, self.backend.state.claims))
-                            else:
-                                logits = np.asarray(logits)
-                        if expert_rows:
-                            count_latent(1, claims)
-                        ds.count(dispatches=1, syncs=1,
-                                 upload_bytes=page_table.nbytes)
-                        rep.decode_steps += 1
-                        rep.slot_steps += B    # the batch always runs full
-                        rep.useful_slot_steps += len(active)
-                        lengths[active] += 1
-                        remaining[active] -= 1
-                        with TraceAnnotation("engine.deliver"):
-                            toks = self._sample_batch(logits[active])
-                            for i, s in enumerate(active):
-                                req = slots[s]
-                                tok = int(toks[i])
-                                req.generated.append(tok)
-                                pending[s] = tok
-                                if req.done:
-                                    finish(s)
-                    if paged:
-                        rep.peak_live_pages = max(rep.peak_live_pages,
-                                                  alloc.live_count)
-                        rep.peak_demand_pages = max(rep.peak_demand_pages,
-                                                    alloc.demand_count)
-                elif not sched.exhausted:
-                    nxt = sched.next_arrival()
-                    if nxt is not None and nxt > step:
-                        step = nxt      # idle: fast-forward to next arrival
-                        continue
+    def _admit(self, s: int, serve: list[str] | None) -> bool:
+        """Admit the earliest admissible ready head of the ``serve``
+        queues into free slot ``s``. True if a queue changed (a request
+        admitted, or failed as one that can never fit), so the slot is
+        retried; False if nothing can be admitted now."""
+        e, sched, rep = self.ecfg, self._sched, self._rep
+        for req in sched.ready_heads(serve):
+            with TraceAnnotation("engine.admit", rid=req.rid):
+                t = self._tenant_of(req)
+                be, ctx = t.backend, req.context_tokens
+                assert len(ctx) >= 1, "empty prompts are not admissible"
+                if be.paged:
+                    if not be.can_ever_fit(t.pgr, len(req.prompt),
+                                           req.max_new_tokens, len(ctx)):
+                        self._reject(sched.pop_ready(req))   # fail fast
+                        return True
+                    rows = be.admission_rows(t.pgr, len(ctx))
+                    sh_pages, sh_tokens = (t.sharer.plan(req, ctx)
+                                           if t.sharer is not None
+                                           else ([], 0))
+                    need, a = len(rows) - len(sh_pages), t.alloc
+                    if not a.can_alloc(need) and t.sharer is not None:
+                        # index-only pages are cache: reclaim them before
+                        # making the request wait, keeping the ones the
+                        # plan maps by reference
+                        t.sharer.index.evict_lru(a, need - a.free_count,
+                                                 protect=set(sh_pages))
+                    fits = a.can_alloc(need)
+                    if self._policy_waits(req, t, need, fits):
+                        return False
+                    if not fits:
+                        continue        # another tenant's head may admit
+                    sched.pop_ready(req)
+                    if sh_pages:
+                        a.share(req.rid, sh_pages)
+                        req.shared_pages += len(sh_pages)
+                        rep.shared_page_hits += len(sh_pages)
+                    pages = a.alloc(req.rid, need)
+                    self._page_table[s, :] = TRASH_PAGE
+                    self._page_table[s, rows] = sh_pages + pages
+                    with TraceAnnotation("engine.prefill"):
+                        if sh_tokens >= len(ctx):
+                            logits = None   # fully cached re-admission
+                        elif sh_tokens:
+                            logits = be.prefill_shared(
+                                ctx, req.extras, s, pages, sh_pages,
+                                sh_tokens)
+                        else:
+                            logits = be.prefill(ctx, req.extras, s, pages)
+                    bucket = e.prefill_bucket
+                    full = -(-len(ctx) // bucket) * bucket
+                    computed = 0 if sh_tokens >= len(ctx) else (
+                        -(-(len(ctx) - sh_tokens) // bucket) * bucket)
+                    rep.prefill_tokens += computed
+                    rep.prefill_tokens_saved += full - computed
+                    if computed:
+                        rep.prefill_calls += 1
+                        req.prefills += 1
+                        tracing.PREFILLS.add(len(ctx) - sh_tokens, computed)
+                    if t.sharer is not None:
+                        t.sharer.record(a, ctx, len(ctx),
+                                        self._page_table[s])
                 else:
-                    break
+                    sched.pop_ready(req)
+                    with TraceAnnotation("engine.prefill"):
+                        logits = be.prefill(ctx, req.extras, s, None)
+                    rep.prefill_calls += 1
+                    rep.prefill_tokens += len(ctx)
+                    req.prefills += 1
+                    tracing.PREFILLS.add(len(ctx), len(ctx))
+                req.admitted_step = self.step
+                self._slots[s], self._owner[s] = req, t
+                self._lengths[s] = len(ctx)
+                self._ds.touch(s)
+                if req.generated:       # re-admission after preemption
+                    self._pending[s] = req.generated[-1]
+                    self._remaining[s] = (req.max_new_tokens
+                                          - len(req.generated))
+                else:
+                    tok = int(self._sample_batch(logits)[0])
+                    req.generated.append(tok)
+                    self._pending[s] = tok
+                    self._remaining[s] = req.max_new_tokens - 1
+                    t.tokens += 1
+                    if req.done:
+                        self._finish(s)   # the slot is retried
+                return True
+        return False
 
-                step += 1
-                if step > e.max_steps:
-                    raise RuntimeError("engine exceeded max_steps")
+    def _grow(self, active: list[int]) -> None:
+        """Page growth, copy-on-write and preemption for the paged slots
+        of the tenants that decode this step. ``active`` loses the slots
+        this preempts or finishes."""
+        e, rep, ds = self.ecfg, self._rep, self._ds
+        page, M = e.page_size, e.max_pages_per_seq
+        slots, owner = self._slots, self._owner
+        page_table, lengths = self._page_table, self._lengths
 
-        if paged:
-            if sharer is not None:      # drop the index's neutral refs
-                sharer.index.release_all(alloc)
-            arena.check()
-            assert alloc.live_count == 0, "pages leaked past completion"
-        rep.preemptions = sched.preemptions
-        rep.device_dispatches = ds.device_dispatches
-        rep.host_syncs = ds.host_syncs
-        rep.page_table_upload_bytes = ds.page_table_upload_bytes
-        rep.wall_s = time.monotonic() - t_run
+        def claim_one(s: int, t: _Tenant) -> bool:
+            """Free one page of ``t``'s lease for slot ``s``: index cache
+            first, then preemption of a victim of the same tenant (page
+            ids are tenant-local, so a neighbour's pages cannot back this
+            growth; a victim's pages may land in the index, evictable
+            next iteration, so the loop still strictly shrinks live
+            state). False if ``s`` itself was preempted."""
+            a = t.alloc
+            if not a.can_alloc(1):
+                self._policy_starved(t)
+            while not a.can_alloc(1):
+                if t.sharer is not None and t.sharer.index.evict_lru(a, 1):
+                    continue
+                victim = Scheduler.pick_victim(
+                    [(v, slots[v]) for v in active if owner[v] is t],
+                    exclude=s)
+                if victim is None or victim[0] == s:
+                    self._preempt(s)
+                    active.remove(s)
+                    return False
+                self._preempt(victim[0])
+                active.remove(victim[0])
+            return True
+
+        for s in list(active):
+            t = owner[s]
+            if t is None or t.alloc is None or not t.decodes:
+                # preempted above, unpaged, or held back this step (growing
+                # a slot that does not decode would re-fire every blocked
+                # step and orphan the previous page in the same row)
+                continue
+            req = slots[s]
+            if lengths[s] % page != 0:
+                # mid-page: the next decode appends into the tail page — if
+                # it is still shared (re-admission mapped a cached tail),
+                # copy-on-write exactly that page first
+                if t.sharer is None:
+                    continue
+                row = lengths[s] // page
+                old = int(page_table[s, row])
+                if t.alloc.refcount(old) < 2 or not claim_one(s, t):
+                    continue
+                new = t.alloc.alloc(req.rid, 1)
+                t.backend.copy_page(old, new[0])
+                t.alloc.free_page(req.rid, old)
+                page_table[s, row] = new[0]
+                ds.touch(s)
+                req.cow_copies += 1
+                rep.cow_copies += 1
+                continue
+            row = lengths[s] // page
+            R = t.backend.ring_rows
+            if R is None and row >= M:  # table row full: stop
+                req.truncated = True
+                self._finish(s)
+                active.remove(s)
+                continue
+            if R is not None:
+                # a ring wraps into row ``pi % R`` and frees the page there
+                # first: the ring holds ceil(window/page)+1 rows, so that
+                # page's positions all lie outside the attention window
+                row %= R
+                old = int(page_table[s, row])
+                if old != TRASH_PAGE:
+                    t.alloc.free_page(req.rid, old)
+                    page_table[s, row] = TRASH_PAGE
+            if not claim_one(s, t):
+                continue
+            page_table[s, row] = t.alloc.alloc(req.rid, 1)[0]
+            ds.touch(s)
+
+    def _horizon(self, groups) -> tuple[int, str]:
+        """The safe horizon: no schedulable event may land inside it, so h
+        steps run device-side are step-for-step identical to h per-step
+        iterations of this loop. ``cause`` names the cut that bound h (a
+        later cut takes it over only by cutting shorter)."""
+        sched, step = self._sched, self.step
+        h, cause = self.ecfg.horizon, "cap"
+        cap = self._policy_horizon()
+        if cap < h:
+            h, cause = cap, "policy"
+        nxt = sched.next_arrival()
+        if nxt is not None and nxt - step < h:
+            h, cause = nxt - step, "arrival"
+        if sched.peek_ready() is not None and \
+                any(r is None for r in self._slots):
+            # a free slot retries admission per step
+            h, cause = 1, "admit"
+        fin = min(int(self._remaining[s]) for _, g in groups for s in g)
+        if fin < h:
+            h, cause = fin, "finish"
+        edge = min((t.pgr.steps_to_boundary(int(self._lengths[s]))
+                    for t, g in groups if t.alloc is not None for s in g),
+                   default=h)
+        if edge < h:                    # growth / ring wrap
+            h, cause = edge, "page"
+        return max(1, h), cause
+
+    def _decode(self, active: list[int]) -> tuple[int, list[_Tenant]]:
+        """One fused horizon, or one per-step dispatch, per decoding
+        tenant, and delivery. Returns the steps taken and the tenants
+        that ran them."""
+        e, rep, ds = self.ecfg, self._rep, self._ds
+        B = e.num_slots
+        slots, owner = self._slots, self._owner
+        page_table, lengths = self._page_table, self._lengths
+        pending, remaining = self._pending, self._remaining
+        if len(self._tenants) == 1:
+            groups = [(self._tenants[0], active)]
+        else:
+            groups = [(t, g) for t in self._tenants
+                      if (g := [s for s in active if owner[s] is t])]
+        h, cause = 1, "unfused"
+        if self._fused:
+            h, cause = self._horizon(groups)
+            with TraceAnnotation("engine.sync"):
+                ds.sync(page_table, lengths, pending, remaining)
+        # bookkeeping below (finish steps, arena epochs) sees the
+        # horizon's last step, exactly as the per-step loop would
+        self.step += h - 1
+        decoded, served = [], 0
+        for t, g in groups:
+            if not t.decodes:
+                continue                # its slots wait this step
+            be = t.backend
+            act = np.zeros((B,), bool)
+            act[g] = True
+            tracing.DISPATCHES.add(h, len(g), cause)
+            if self._fused:
+                # tenants chain through the shared device arrays: each
+                # call masks to its own slots and donates the loop arrays
+                # to the next tenant's call
+                with TraceAnnotation("engine.decode"):
+                    out, p_d, l_d, r_d = be.decode_fused(
+                        ds.pending, ds.lengths, ds.remaining, ds.table,
+                        act, h)
+                with TraceAnnotation("engine.wait"):
+                    if be.expert_rows_per_step:   # claims ride along
+                        out, claims = jax.device_get((out, be.state.claims))
+                    else:
+                        out = np.asarray(out)     # ONE host sync
+                ds.adopt(p_d, l_d, r_d)
+                ds.count(dispatches=1, syncs=1)
+            else:
+                # page ids are tenant-local: other tenants' rows are
+                # blanked so this backend never gathers past its pool
+                with TraceAnnotation("engine.decode"):
+                    out = be.decode(
+                        np.where(act, pending, 0).astype(np.int32),
+                        np.where(act[:, None], page_table, TRASH_PAGE),
+                        np.where(act, lengths, 0).astype(np.int32), act)
+                with TraceAnnotation("engine.wait"):
+                    if be.expert_rows_per_step:
+                        out, claims = jax.device_get((out, be.state.claims))
+                    else:
+                        out = np.asarray(out)
+                ds.count(dispatches=1, syncs=1,
+                         upload_bytes=page_table.nbytes)
+            if be.expert_rows_per_step:
+                # live claims on held experts against the expert rows
+                # computed, and the latent rows live (each slot attends
+                # over its context and the new tokens) against the rows
+                # the whole page table's gather reads, per layer; taken
+                # before the lengths advance
+                tracing.ROUTES.add(int(claims), h * be.expert_rows_per_step)
+                live = (int(lengths[g].sum()) * h
+                        + len(g) * h * (h + 1) // 2)
+                tracing.LATENT_READS.add(
+                    live, B * e.max_pages_per_seq * e.page_size * h)
+            lengths[g] += h
+            remaining[g] -= h
+            with TraceAnnotation("engine.deliver"):
+                if self._fused:
+                    for s in g:
+                        req = slots[s]
+                        req.generated.extend(int(x) for x in out[:h, s])
+                        pending[s] = int(out[h - 1, s])
+                        if req.done:
+                            self._finish(s)
+                else:
+                    toks = self._sample_batch(out[g])
+                    for i, s in enumerate(g):
+                        req = slots[s]
+                        tok = int(toks[i])
+                        req.generated.append(tok)
+                        pending[s] = tok
+                        if req.done:
+                            self._finish(s)
+            t.tokens += h * len(g)
+            served += len(g)
+            decoded.append(t)
+        if served:
+            rep.decode_steps += h
+            rep.slot_steps += B * h     # the batch always runs full
+            rep.useful_slot_steps += served * h
+        if self._paged:
+            live = [(t.alloc, t.backend.page_bytes) for t in self._paged]
+            rep.peak_live_pages = max(rep.peak_live_pages,
+                                      sum(a.live_count for a, _ in live))
+            rep.peak_demand_pages = max(rep.peak_demand_pages,
+                                        sum(a.demand_count for a, _ in live))
+            rep.peak_live_page_bytes = max(
+                rep.peak_live_page_bytes,
+                sum(a.live_count * pb for a, pb in live))
+            rep.peak_demand_page_bytes = max(
+                rep.peak_demand_page_bytes,
+                sum(a.demand_count * pb for a, pb in live))
+        return h, decoded
+
+    def finish_run(self) -> EngineReport:
+        rep = self._rep
+        for t in self._paged:
+            if t.sharer is not None:    # drop the index's neutral refs
+                t.sharer.index.release_all(t.alloc)
+        if self.arena is not None:
+            self.arena.check()
+        for t in self._paged:
+            assert t.alloc.live_count == 0, "pages leaked past completion"
+        rep.preemptions = self._sched.preemptions
+        rep.device_dispatches = self._ds.device_dispatches
+        rep.host_syncs = self._ds.host_syncs
+        rep.page_table_upload_bytes = self._ds.page_table_upload_bytes
+        rep.wall_s = time.monotonic() - self._t_run
         return rep
+
+    # -- slot lifecycle ----------------------------------------------------
+
+    def _clear_slot(self, s: int) -> None:
+        req, t = self._slots[s], self._owner[s]
+        if t.sharer is not None:
+            # index the pages this request completed (incl. during decode)
+            # BEFORE dropping its refs: the neutral refs keep the prefix
+            # warm for later prompts / re-admission
+            t.sharer.record(t.alloc, req.context_tokens, self._lengths[s],
+                            self._page_table[s])
+        self._slots[s] = self._owner[s] = None
+        self._page_table[s, :] = TRASH_PAGE
+        self._lengths[s] = 0
+        self._pending[s] = 0
+        self._remaining[s] = 0
+        self._ds.touch(s)
+        if t.alloc is not None:
+            t.alloc.free_owner(req.rid)
+        t.backend.release_slot(s)
+
+    def _finish(self, s: int) -> None:
+        self._slots[s].done_step = self.step
+        self._rep.completed.append(self._slots[s])
+        self._clear_slot(s)
+
+    def _preempt(self, s: int) -> None:
+        req = self._slots[s]
+        self._clear_slot(s)
+        self._sched.requeue(req)
+
+    def _reject(self, req: Request) -> None:
+        req.truncated = True
+        req.done_step = self.step
+        self._rep.completed.append(req)
+
+    # -- steppable-loop accessors (the fleet router reads these) -----------
+
+    @property
+    def report(self) -> EngineReport:
+        return self._rep
+
+    def inject(self, requests: list[Request]) -> None:
+        """Hand the engine more requests mid-run (fleet dispatch)."""
+        self._sched.inject(requests)
+
+    def occupied_slots(self) -> int:
+        return sum(1 for r in self._slots if r is not None)
+
+    def backlog(self) -> int:
+        """Requests queued but not in a slot (ready + future arrivals)."""
+        return len(self._sched)
+
+    def load(self) -> int:
+        """Routing load signal: occupied slots + queued requests."""
+        return self.occupied_slots() + self.backlog()
+
+    def oldest_queued_age(self) -> int:
+        """Steps the longest-waiting READY request has been queued.
+        Load alone hides a stuck head (two replicas at equal load, one
+        with a request aging behind a page-starved tenant), so the
+        fleet router folds this in as a tiebreak."""
+        arr = self._sched.oldest_ready_arrival()
+        return max(0, self.step - arr) if arr is not None else 0
+
+    def drain(self) -> list[Request]:
+        """Failover: preempt every in-flight request and pull the whole
+        queue out, returning ALL unfinished requests for re-admission on
+        another replica (their generated tokens ride along, so nothing
+        restarts from scratch beyond the re-prefill)."""
+        for s, r in enumerate(self._slots):
+            if r is not None:
+                self._preempt(s)
+        return self._sched.drain()
 
 
 def _home_sharding(params):
@@ -1177,26 +1353,6 @@ def _home_sharding(params):
 
 def _state_bytes(state) -> int:
     return sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(state))
-
-
-def _growth_row(backend, alloc, page_table, s: int, pi: int, rid: int
-                ) -> int:
-    """Table row for a slot's next page. Linear backends grow into row
-    ``pi``; ring backends wrap into ``pi % ring_rows`` — and the page
-    already in that row is freed FIRST, which is safe exactly because
-    the ring holds ceil(window/page)+1 rows: the wrapped-out page's
-    positions are all <= pos - window, outside the attention window.
-    Both engines' growth loops share this so the invariant lives in one
-    place."""
-    R = backend.ring_rows
-    if R is None:
-        return pi
-    row = pi % R
-    old = int(page_table[s, row])
-    if old != TRASH_PAGE:
-        alloc.free_page(rid, old)
-        page_table[s, row] = TRASH_PAGE
-    return row
 
 
 # --- multi-tenant pooled engine ------------------------------------------------
@@ -1268,27 +1424,8 @@ class PooledReport(EngineReport):
     repartitions: int = 0              # arena epochs executed
     pages_moved: int = 0               # leases moved between tenants
     aging_blocks: int = 0              # admission scans blocked by aging
-    peak_live_page_bytes: int = 0      # tenants' page sizes differ
-    peak_demand_page_bytes: int = 0    # live minus index-only, in bytes
     model_tokens: dict = dataclasses.field(default_factory=dict)
     stall_steps_by_model: dict = dataclasses.field(default_factory=dict)
-
-    @property
-    def kv_bytes_peak(self) -> int:
-        """Peak live cache bytes summed per tenant at its OWN page size
-        (an MLA latent page is far smaller than a dense KV page, so
-        pages * max(page_bytes) would materially overstate the peak)."""
-        if self.page_bytes:
-            return self.peak_live_page_bytes + self.slot_state_bytes
-        return self.cache_bytes_alloc
-
-    @property
-    def kv_demand_bytes_peak(self) -> int:
-        """Peak referenced-by-some-request cache bytes per tenant page
-        size (shared pages once, index-only cache excluded)."""
-        if self.page_bytes:
-            return self.peak_demand_page_bytes + self.slot_state_bytes
-        return self.cache_bytes_alloc
 
     @property
     def decode_tokens_per_step(self) -> float:
@@ -1321,7 +1458,7 @@ class PooledReport(EngineReport):
         return s
 
 
-class PooledEngine:
+class PooledEngine(Engine):
     """Continuous batching for a model zoo sharing one accelerator pool.
 
     Per-model backends (one jitted prefill/decode pair each) split one
@@ -1343,6 +1480,9 @@ class PooledEngine:
     keeps a single swappable tenant hot at a time, so it cannot use the
     shared step — that utilization gap, plus its per-switch reloads, is
     exactly what the reload-aware policy is measured against.
+
+    The loop is ``Engine``'s; this class builds the tenants and answers
+    its policy points from the modeled pool.
     """
 
     def __init__(self, pool: ModelPool, params: dict,
@@ -1370,10 +1510,9 @@ class PooledEngine:
             paged_shares)
         self.page_split = self.arena.page_split
         self.backends = {}
-        self._pgr = {}                 # per-tenant pager geometry
+        tenants = []
         for e in pool.plan.entries:
-            backend_cls = resolve_backend(e.cfg)
-            ecfg_t = self.ecfg
+            ecfg_t, pgr = self.ecfg, self.ecfg.pager
             if e.model_id in self.page_split:
                 # tenant's device pool backs its provisioned rows (+ its
                 # own trash page): with repartition off that is exactly
@@ -1385,232 +1524,78 @@ class PooledEngine:
                 # is opportunistic and can shrink back, so a request
                 # must be completable under the static share alone.
                 ecfg_t = dataclasses.replace(
-                    self.ecfg,
-                    num_pages=self.arena.cap(e.model_id) + 1)
-                self._pgr[e.model_id] = dataclasses.replace(
+                    self.ecfg, num_pages=self.arena.cap(e.model_id) + 1)
+                pgr = dataclasses.replace(
                     self.ecfg,
                     num_pages=self.page_split[e.model_id] + 1).pager
-            else:
-                self._pgr[e.model_id] = ecfg_t.pager
-            self.backends[e.model_id] = backend_cls(
-                e.cfg, params[e.model_id], ecfg_t)
+            backend = resolve_backend(e.cfg)(e.cfg, params[e.model_id],
+                                             ecfg_t)
+            self.backends[e.model_id] = backend
             if e.model_id in self.page_split:
-                self.arena.register_page_bytes(
-                    e.model_id, self.backends[e.model_id].page_bytes)
+                self.arena.register_page_bytes(e.model_id,
+                                               backend.page_bytes)
+            tenants.append(_Tenant(e.model_id, backend, pgr))
         if self.ecfg.repartition == "off":
             assert sum(n + 1 for n in self.page_split.values()) \
                 <= self.ecfg.num_pages, \
                 "physical pages exceed the pool budget"
-        self.rng = np.random.default_rng(self.ecfg.seed)
-        self._sample_batch = make_batch_sampler(
-            self.rng, self.ecfg.greedy, self.ecfg.temperature)
-        self._sample = make_sampler(self.rng, self.ecfg.greedy,
-                                    self.ecfg.temperature)
-        self._fused = self.ecfg.greedy and self.ecfg.horizon > 1
-
-    # -- main loop ---------------------------------------------------------
-    # The loop is split into start / step_once / finish_run so a caller
-    # can interleave OTHER work between engine steps: the fleet tier
-    # drives N replicas in lockstep ticks, injecting requests and faults
-    # mid-run. ``run`` composes the three for the single-pool case.
+        self._by_model = {t.model_id: t for t in tenants}
+        self._init_loop(tenants, None)
 
     def start(self, requests: list[Request]) -> "PooledEngine":
-        e, pool = self.ecfg, self.pool
-        self._sched = MultiQueueScheduler(requests)
-        # the arena hands each paged tenant its leased allocator (a fresh
-        # run starts from the initial demand-proportional partition)
-        self.arena.reset_runtime()
-        self._allocs = {m: self.arena.allocator(m) for m in self.page_split}
-        # one prefix index per eligible tenant: page ids are tenant-local
-        # and token-id equality only implies KV equality within a model
-        self._sharers = {
-            m: _PrefixSharing(self._pgr[m]) for m in self.page_split
-            if e.prefix_sharing
-            and getattr(self.backends[m], "prefix_sharing", False)}
-        pool.reset_runtime()
-
-        B = e.num_slots
-        self._order = list(pool.model_ids)
-        self._slots: list[Request | None] = [None] * B
-        self._page_table = np.zeros((B, e.pager.max_pages_per_seq),
-                                    np.int32)
-        self._lengths = np.zeros((B,), np.int32)
-        self._pending = np.zeros((B,), np.int32)
-        self._remaining = np.zeros((B,), np.int32)
-        # one device twin spans every tenant: the fused dispatches chain
-        # through it (model A's donated outputs feed model B's inputs)
-        self._ds = DeviceLoopState(B, e.pager.max_pages_per_seq)
-        self._rep = PooledReport(
-            name=f"pool/{e.policy}", num_slots=B, policy=e.policy,
-            stream=e.stream,
-            page_bytes=max(
-                (self.backends[m].page_bytes for m in self.page_split),
-                default=0),
-            slot_state_bytes=sum(b.slot_state_bytes
-                                 for b in self.backends.values()),
-            cache_bytes_alloc=sum(_state_bytes(b.state)
-                                  for b in self.backends.values()),
-            model_tokens={m: 0 for m in self._order},
-            stall_steps_by_model={m: 0 for m in self._order})
-        self._t_run = time.monotonic()
-        self.step = 0
+        self.pool.reset_runtime()
+        super().start(requests)
+        self._serve: list[str] = []
         self._rr_current: str | None = None
         self._rr_left = 0
         self._blocked_since: dict[int, int] = {}  # rid -> first blocked step
         return self
 
-    # -- steppable-loop accessors (the fleet router reads these) -----------
+    def _new_report(self) -> PooledReport:
+        e, ids = self.ecfg, self.pool.model_ids
+        return PooledReport(
+            name=f"pool/{e.policy}", policy=e.policy, stream=e.stream,
+            model_tokens=dict.fromkeys(ids, 0),
+            stall_steps_by_model=dict.fromkeys(ids, 0),
+            **self._report_base())
 
-    @property
-    def report(self) -> PooledReport:
-        return self._rep
+    def finish_run(self) -> PooledReport:
+        pool = self.pool
+        rep = super().finish_run()
+        self.arena.check(slab_used=pool.slab_used,
+                         pinned_bytes=pool.plan.pinned_bytes)
+        rep.reload_bytes = pool.reload_bytes_total
+        rep.restream_bytes = pool.restream_bytes_total
+        rep.reload_events = pool.reload_events
+        rep.evictions = pool.evictions
+        rep.deferred_activations = pool.deferred_activations
+        rep.repartitions = self.arena.repartitions
+        rep.pages_moved = self.arena.pages_moved
+        rep.model_tokens.update((t.model_id, t.tokens) for t in self._tenants)
+        return rep
 
-    def inject(self, requests: list[Request]) -> None:
-        """Hand this replica more requests mid-run (fleet dispatch)."""
-        self._sched.inject(requests)
+    # -- policy points ------------------------------------------------------
 
-    def occupied_slots(self) -> int:
-        return sum(1 for r in self._slots if r is not None)
-
-    def backlog(self) -> int:
-        """Requests queued but not in a slot (ready + future arrivals)."""
-        sch = self._sched
-        return (sum(sch.ready_count(m) for m in sch.ready_models())
-                + len(sch._pending))
-
-    def load(self) -> int:
-        """Routing load signal: occupied slots + queued requests."""
-        return self.occupied_slots() + self.backlog()
-
-    def oldest_queued_age(self) -> int:
-        """Steps the longest-waiting READY request has been queued.
-        Load alone hides a stuck head (two replicas at equal load, one
-        with a request aging behind a page-starved tenant), so the
-        fleet router folds this in as a tiebreak."""
-        arr = self._sched.oldest_ready_arrival()
-        return max(0, self.step - arr) if arr is not None else 0
-
-    def drain(self) -> list[Request]:
-        """Failover: preempt every in-flight request and pull the whole
-        queue out, returning ALL unfinished requests for re-admission on
-        another replica (their generated tokens and MoE route traces ride
-        along, so nothing restarts from scratch beyond the re-prefill)."""
-        for s in range(len(self._slots)):
-            if self._slots[s] is not None:
-                self._preempt(s)
-        return self._sched.drain()
-
-    # -- slot lifecycle -----------------------------------------------------
-
-    def _clear_slot(self, s: int) -> None:
-        req = self._slots[s]
-        sharer = self._sharers.get(req.model_id)
-        if sharer is not None:
-            # index the completed pages before the refs drop: the
-            # neutral refs keep the prefix warm for later prompts
-            sharer.record(self._allocs[req.model_id], req.context_tokens,
-                          self._lengths[s], self._page_table[s])
-        self._slots[s] = None
-        self._page_table[s, :] = TRASH_PAGE
-        self._lengths[s] = 0
-        self._pending[s] = 0
-        self._remaining[s] = 0
-        self._ds.touch(s)
-        if req.model_id in self._allocs:
-            self._allocs[req.model_id].free_owner(req.rid)
-        self.backends[req.model_id].release_slot(s)
-
-    def _finish(self, s: int) -> None:
-        self._slots[s].done_step = self.step
-        self._rep.completed.append(self._slots[s])
-        self._clear_slot(s)
-
-    def _preempt(self, s: int) -> None:
-        req = self._slots[s]
-        self._clear_slot(s)
-        self._sched.requeue(req)
-
-    def _reject(self, req: Request) -> None:
-        req.truncated = True
-        req.done_step = self.step
-        self._rep.completed.append(req)
+    def _tenant_of(self, req: Request) -> _Tenant:
+        return self._by_model[req.model_id]
 
     def _active_models(self) -> list[str]:
-        got = {r.model_id for r in self._slots if r is not None}
-        return [m for m in self._order if m in got]
+        return [t.model_id for t in self._tenants if t in self._owner]
 
-    def _pick_admissible(self, serve: list[str]) -> Request | None:
-        """Earliest ready head whose tenant can admit now. Page
-        pressure is tenant-local (partitioned sub-ranges), so a
-        page-starved tenant waits without blocking its neighbours —
-        but only up to the aging bound: once a blocked head has been
-        bypassed for ``max_bypass_steps``, the scan BLOCKS for it
-        instead of admitting later arrivals past it. Heads that can
-        never fit are failed fast along the way."""
-        e, sched, step = self.ecfg, self._sched, self.step
-        while True:
-            for req in sched.ready_heads(serve):
-                backend = self.backends[req.model_id]
-                if not backend.paged:
-                    return req
-                pgr_t = self._pgr[req.model_id]
-                ctx_len = len(req.context_tokens)
-                if not backend.can_ever_fit(pgr_t, len(req.prompt),
-                                            req.max_new_tokens,
-                                            ctx_len):
-                    self._blocked_since.pop(req.rid, None)
-                    self._reject(sched.pop_ready(req))
-                    break           # queues changed: rescan heads
-                rows = backend.admission_rows(pgr_t, ctx_len)
-                a = self._allocs[req.model_id]
-                need = len(rows)
-                sharer = self._sharers.get(req.model_id)
-                if sharer is not None:
-                    sh_pages, _ = sharer.plan(req, req.context_tokens)
-                    need -= len(sh_pages)
-                    if not a.can_alloc(need):
-                        # reclaim index-only cache pages, protecting the
-                        # ones the plan is about to map by reference
-                        sharer.index.evict_lru(a, need - a.free_count,
-                                               protect=set(sh_pages))
-                if a.can_alloc(need):
-                    self._blocked_since.pop(req.rid, None)
-                    return req
-                # page-blocked head: feed the arena's load signal and
-                # age it — an over-aged head stops the scan so later
-                # arrivals cannot bypass it indefinitely
-                first = self._blocked_since.setdefault(req.rid, step)
-                self.arena.note_starved(req.model_id, step, want=need)
-                if e.max_bypass_steps \
-                        and step - first >= e.max_bypass_steps:
-                    self._rep.aging_blocks += 1
-                    return None
-            else:
-                return None
+    def _charge_stall(self, m: str, stall: int) -> None:
+        self._rep.stall_steps += stall
+        self._rep.stall_steps_by_model[m] += stall
+        self.step += stall
 
-    def step_once(self) -> bool:
-        """Advance the pool one engine step. Returns False when nothing
-        can progress — every queue is empty and no slot is occupied (the
-        single-pool ``run`` stops there; the fleet keeps an idle replica
-        alive because the router may inject work or faults later) — and
-        True otherwise, including idle fast-forwards to a future
-        arrival."""
-        e, pool = self.ecfg, self.pool
-        B, page = e.num_slots, e.pager.page_size
-        M = e.pager.max_pages_per_seq
-        sched, rep, allocs = self._sched, self._rep, self._allocs
-        slots, page_table = self._slots, self._page_table
-        lengths, pending = self._lengths, self._pending
-
-        sched.release_arrivals(self.step)
-
-        # -- drain queues no backend can ever serve ------------------
+    def _policy_step(self) -> list[str]:
+        """Fail the queues no tenant can serve, run the activation
+        policy, and gate each tenant's decode on its weights' stream."""
+        e, pool, sched = self.ecfg, self.pool, self._sched
         for m in sched.ready_models():
-            if m not in self.backends or not pool.servable(m):
+            if m not in self._by_model or not pool.servable(m):
                 while (req := sched.peek_ready([m])) is not None:
                     self._reject(sched.pop_ready(req))
 
-        # -- activation policy ---------------------------------------
         if e.policy == "round_robin":
             ready = sched.ready_models()
             rr = self._rr_current
@@ -1618,7 +1603,7 @@ class PooledEngine:
                       or (rr not in self._active_models()
                           and sched.ready_count(rr) == 0))
             if switch and ready:
-                order = self._order
+                order = list(pool.model_ids)
                 start = ((order.index(rr) + 1) % len(order)
                          if rr is not None else 0)
                 nxt = next((order[(start + i) % len(order)]
@@ -1627,23 +1612,20 @@ class PooledEngine:
                            None)
                 if nxt is not None and nxt != rr:
                     # naive swap: drop everything, load the next model
-                    for s in range(B):
-                        if slots[s] is not None:
+                    for s, r in enumerate(self._slots):
+                        if r is not None:
                             self._preempt(s)
                     for m in list(pool.hot_models()):
                         pool.evict(m)
                     stall, _ = pool.try_activate(nxt, self.step)
-                    rep.stall_steps += stall
-                    rep.stall_steps_by_model[nxt] += stall
-                    self.step += stall
+                    self._charge_stall(nxt, stall)
                     self._rr_current, self._rr_left = nxt, e.rr_quantum
                 elif nxt is not None:
                     self._rr_left = e.rr_quantum
             serve = [self._rr_current] if self._rr_current is not None \
                 else []
         else:
-            cold = [m for m in sched.ready_models()
-                    if not pool.is_hot(m)]
+            cold = [m for m in sched.ready_models() if not pool.is_hot(m)]
             if cold:
                 # highest queued-demand per reload byte activates
                 # first; if it must wait (hysteresis), a smaller cold
@@ -1651,26 +1633,23 @@ class PooledEngine:
                 cold.sort(key=lambda m: (
                     -sched.pending_demand(m)
                     / max(pool.plan.entry(m).reload_bytes, 1), m))
+                active = self._active_models()
                 protected = frozenset(
                     m for m in pool.hot_models()
-                    if m in self._active_models()
-                    or sched.ready_count(m) > 0)
+                    if m in active or sched.ready_count(m) > 0)
                 for m in cold:
                     if e.stream == "layer":
                         # layer-granular: reserve the slab, then let
                         # the per-layer schedule stream behind compute
                         # (stalls only surface as prefetch misses,
-                        # charged after the decode section)
+                        # charged at the step's end)
                         if pool.begin_stream(m, self.step, protected) \
                                 is not None:
                             break   # the DMA issues one stream at once
                     else:
                         res = pool.try_activate(m, self.step, protected)
                         if res is not None:
-                            stall, _ = res
-                            rep.stall_steps += stall
-                            rep.stall_steps_by_model[m] += stall
-                            self.step += stall
+                            self._charge_stall(m, res[0])
                             break   # one reload/step: stalls serialize
             if e.stream == "layer":
                 # a mid-stream model joins once it heads the serial
@@ -1680,325 +1659,84 @@ class PooledEngine:
                          if pool.decode_ready(m)]
             else:
                 serve = pool.hot_models()
+        for t in self._tenants:
+            # a bounded-slab tenant mid-re-stream, or one queued behind
+            # the serial DMA, neither grows nor decodes this step
+            t.decodes = e.stream != "layer" \
+                or pool.decode_ready(t.model_id)
+        self._serve = serve
+        return serve
 
-        # -- admission into free slots -------------------------------
-        admitting = True
-        for s in range(B):
-            while admitting and slots[s] is None:
-                req = self._pick_admissible(serve)
-                if req is None:
-                    admitting = False
-                    break
-                backend = self.backends[req.model_id]
-                ctx = req.context_tokens
-                assert len(ctx) >= 1, "empty prompts are not admissible"
-                if backend.paged:
-                    sched.pop_ready(req)
-                    a = allocs[req.model_id]
-                    rows = backend.admission_rows(
-                        self._pgr[req.model_id], len(ctx))
-                    sharer = self._sharers.get(req.model_id)
-                    sh_pages, sh_tokens = (
-                        sharer.plan(req, ctx) if sharer is not None
-                        else ([], 0))
-                    if sh_pages:
-                        a.share(req.rid, sh_pages)
-                        req.shared_pages += len(sh_pages)
-                        rep.shared_page_hits += len(sh_pages)
-                    pages = a.alloc(req.rid, len(rows) - len(sh_pages))
-                    page_table[s, :] = TRASH_PAGE
-                    page_table[s, rows] = sh_pages + pages
-                    if sh_tokens >= len(ctx):
-                        logits = None   # fully cached re-admission
-                    elif sh_tokens:
-                        logits = backend.prefill_shared(
-                            ctx, req.extras, s, pages, sh_pages,
-                            sh_tokens)
-                    else:
-                        logits = backend.prefill(ctx, req.extras, s, pages)
-                    full = (-(-len(ctx) // e.prefill_bucket)
-                            * e.prefill_bucket)
-                    computed = 0 if sh_tokens >= len(ctx) else (
-                        -(-(len(ctx) - sh_tokens) // e.prefill_bucket)
-                        * e.prefill_bucket)
-                    rep.prefill_tokens += computed
-                    rep.prefill_tokens_saved += full - computed
-                    if computed:
-                        rep.prefill_calls += 1
-                        req.prefills += 1
-                    if sharer is not None:
-                        sharer.record(a, ctx, len(ctx), page_table[s])
-                else:
-                    sched.pop_ready(req)
-                    logits = backend.prefill(ctx, req.extras, s, None)
-                    rep.prefill_calls += 1
-                    rep.prefill_tokens += len(ctx)
-                    req.prefills += 1
-                req.admitted_step = self.step
-                slots[s] = req
-                lengths[s] = len(ctx)
-                if req.generated:   # re-admission after preemption
-                    pending[s] = req.generated[-1]
-                    self._remaining[s] = (req.max_new_tokens
-                                          - len(req.generated))
-                    self._ds.touch(s)
-                else:
-                    assert logits is not None
-                    tok = self._sample(logits)
-                    req.generated.append(tok)
-                    pending[s] = tok
-                    self._remaining[s] = req.max_new_tokens - 1
-                    self._ds.touch(s)
-                    rep.model_tokens[req.model_id] += 1
-                    if req.done:
-                        self._finish(s)
+    def _policy_waits(self, req: Request, t: _Tenant, need: int,
+                      fits: bool) -> bool:
+        """Page pressure is tenant-local, so a page-starved head waits
+        without blocking its neighbours — but only up to the aging bound:
+        once it has been bypassed for ``max_bypass_steps``, the scan stops
+        at it instead of admitting later arrivals past it."""
+        if fits:
+            self._blocked_since.pop(req.rid, None)
+            return False
+        # a page-blocked head feeds the arena's load signal and ages
+        first = self._blocked_since.setdefault(req.rid, self.step)
+        self.arena.note_starved(t.model_id, self.step, want=need)
+        if self.ecfg.max_bypass_steps \
+                and self.step - first >= self.ecfg.max_bypass_steps:
+            self._rep.aging_blocks += 1
+            return True
+        return False
 
-        # -- one fused decode step over every hot tenant's slots -----
-        # Weights of all hot tenants sit in HBM simultaneously (the
-        # packed-canvas premise at pool scale), so their slots advance
-        # in the same engine step; the naive round-robin baseline only
-        # ever holds one swappable tenant hot, so it cannot use this
-        # concurrency — that utilization gap is the point.
-        did_compute = False
-        if self._active_models():
-            # page growth / preemption for every paged tenant's slot
-            for s in range(B):
-                if slots[s] is None:
-                    continue
-                mid = slots[s].model_id
-                if not self.backends[mid].paged:
-                    continue
-                if e.stream == "layer" and not pool.decode_ready(mid):
-                    # no decode this step (mid-re-stream / queued
-                    # behind the DMA): growing now would re-fire on
-                    # every blocked step and orphan the previous
-                    # page into the same table row
-                    continue
-                a = allocs[mid]
-                sharer = self._sharers.get(mid)
+    def _policy_starved(self, t: _Tenant) -> None:
+        # growth pressure is the other load signal the arena
+        # repartitions on (preempt == starvation)
+        self.arena.note_starved(t.model_id, self.step)
 
-                def claim_one(s: int, mid: str = mid, a=a,
-                              sharer=sharer) -> bool:
-                    """Free one page for slot ``s``: index cache first,
-                    then same-tenant victim preemption (a victim's
-                    pages may land in the index — evictable next
-                    iteration, so the loop still strictly shrinks live
-                    state). False if ``s`` itself was preempted."""
-                    if not a.can_alloc(1):
-                        # growth pressure is the other load signal the
-                        # arena repartitions on (preempt == starvation)
-                        self.arena.note_starved(mid, self.step)
-                    while not a.can_alloc(1):
-                        if sharer is not None \
-                                and sharer.index.evict_lru(a, 1):
-                            continue
-                        # only same-tenant slots are useful victims —
-                        # the page-id space is partitioned, so a
-                        # neighbour's pages can never back this growth
-                        tenant_active = [
-                            (v, slots[v]) for v in range(B)
-                            if slots[v] is not None
-                            and slots[v].model_id == mid]
-                        victim = Scheduler.pick_victim(tenant_active,
-                                                       exclude=s)
-                        if victim is None or victim[0] == s:
-                            self._preempt(s)
-                            return False
-                        self._preempt(victim[0])
-                    return True
-
-                if lengths[s] % page != 0:
-                    # mid-page: the next decode appends into the tail
-                    # page — if it is still shared (re-admission mapped
-                    # a cached tail), copy-on-write exactly that page
-                    if sharer is None:
-                        continue
-                    row_i = lengths[s] // page
-                    old = int(page_table[s, row_i])
-                    if a.refcount(old) < 2:
-                        continue
-                    if not claim_one(s):
-                        continue
-                    new = a.alloc(slots[s].rid, 1)
-                    self.backends[mid].copy_page(old, new[0])
-                    a.free_page(slots[s].rid, old)
-                    page_table[s, row_i] = new[0]
-                    self._ds.touch(s)
-                    slots[s].cow_copies += 1
-                    rep.cow_copies += 1
-                    continue
-                pi = lengths[s] // page
-                R = self.backends[mid].ring_rows
-                if R is None and pi >= M:
-                    slots[s].truncated = True
-                    self._finish(s)
-                    continue
-                row = _growth_row(self.backends[mid], a, page_table,
-                                  s, pi, slots[s].rid)
-                if not claim_one(s):
-                    continue
-                new = a.alloc(slots[s].rid, 1)
-                page_table[s, row] = new[0]
-                self._ds.touch(s)
-
-            # safe horizon: h > 1 only when no schedulable event —
-            # arrival, admission retry, cold activation, rr switch,
-            # stream/burst accounting, epoch boundary, page boundary,
-            # slot finish — can land mid-horizon, so h fused steps are
-            # step-for-step identical to h per-step iterations
+    def _policy_horizon(self) -> int:
+        """Cuts for events the policy settles per step: the round-robin
+        quantum, DMA ticks and decode bursts, cold tenants retrying
+        activation, and arena epoch boundaries."""
+        e, pool = self.ecfg, self.pool
+        h = e.horizon
+        if e.policy == "round_robin":
+            h = min(h, max(1, self._rr_left))
+        if pool.pcfg.slab_mode == "bounded" or (
+                e.stream == "layer" and pool.streaming):
             h = 1
-            if self._fused:
-                h = e.horizon
-                if e.policy == "round_robin":
-                    h = min(h, max(1, self._rr_left))
-                if pool.pcfg.slab_mode == "bounded" or (
-                        e.stream == "layer" and pool.streaming):
-                    h = 1   # DMA ticks / decode bursts settle per step
-                ready = sched.ready_models()
-                if any(m not in serve for m in ready):
-                    h = 1   # cold tenant retries activation every step
-                if ready and any(r is None for r in slots):
-                    h = 1   # free slot retries admission every step
-                nxt = sched.next_arrival()
-                if nxt is not None:
-                    h = min(h, nxt - self.step)
-                ne = self.arena.next_epoch_step()
-                if ne is not None:      # boundary must land on a step
-                    h = min(h, ne - self.step + 1)
-                for s in range(B):
-                    if slots[s] is None:
-                        continue
-                    h = min(h, int(self._remaining[s]))
-                    if self.backends[slots[s].model_id].paged:
-                        h = min(h, self._pgr[slots[s].model_id]
-                                .steps_to_boundary(int(lengths[s])))
-                h = max(1, h)
-                self._ds.sync(page_table, lengths, pending,
-                              self._remaining)
-            # bookkeeping below (finish steps, arena epoch) sees the
-            # horizon's last step, exactly as the per-step loop would
-            self.step += h - 1
-            self._rr_left -= h - 1
+        if any(m not in self._serve for m in self._sched.ready_models()):
+            h = 1
+        ne = self.arena.next_epoch_step()
+        if ne is not None:      # the boundary must land on a step
+            h = min(h, ne - self.step + 1)
+        return h
 
-            served = 0
-            for m in self._active_models():
-                backend = self.backends[m]
-                m_slots = [s for s in range(B)
-                           if slots[s] is not None
-                           and slots[s].model_id == m]
-                if not m_slots:
-                    continue
-                if e.stream == "layer" and not pool.decode_ready(m):
-                    # a bounded-slab tenant mid-re-stream (or a tenant
-                    # queued behind the serial DMA) skips this step;
-                    # its slots wait while the FIFO drains
-                    continue
-                act = np.zeros((B,), bool)
-                act[m_slots] = True
-                if self._fused:
-                    # tenants chain through the shared device arrays:
-                    # each fused call masks to its own slots (and blanks
-                    # other tenants' table rows on device) and donates
-                    # the loop arrays to the next tenant's call
-                    ds = self._ds
-                    out, p_d, l_d, r_d = backend.decode_fused(
-                        ds.pending, ds.lengths, ds.remaining, ds.table,
-                        act, h)
-                    toks_h = np.asarray(out)   # one host sync/tenant
-                    ds.adopt(p_d, l_d, r_d)
-                    ds.count(dispatches=1, syncs=1)
-                    lengths[m_slots] += h
-                    self._remaining[m_slots] -= h
-                    served += len(m_slots)
-                    for s in m_slots:
-                        req = slots[s]
-                        req.generated.extend(
-                            int(t) for t in toks_h[:h, s])
-                        pending[s] = int(toks_h[h - 1, s])
-                        rep.model_tokens[m] += h
-                        if req.done:
-                            self._finish(s)
-                else:
-                    toks = np.where(act, pending, 0).astype(np.int32)
-                    # page ids are tenant-local: blank out other
-                    # tenants' rows so this backend never gathers past
-                    # its pool
-                    pt_m = np.where(act[:, None], page_table, TRASH_PAGE)
-                    len_m = np.where(act, lengths, 0).astype(np.int32)
-                    logits = np.asarray(backend.decode(toks, pt_m, len_m,
-                                                       act))
-                    self._ds.count(dispatches=1, syncs=1,
-                                   upload_bytes=page_table.nbytes)
-                    lengths[m_slots] += 1
-                    self._remaining[m_slots] -= 1
-                    served += len(m_slots)
-                    stoks = self._sample_batch(logits[m_slots])
-                    for i, s in enumerate(m_slots):
-                        req = slots[s]
-                        tok = int(stoks[i])
-                        req.generated.append(tok)
-                        pending[s] = tok
-                        rep.model_tokens[m] += 1
-                        if req.done:
-                            self._finish(s)
-                # bounded slab: queue this burst's re-stream bytes
-                pool.note_decode_burst(m)
-            if served:
-                did_compute = True
-                rep.decode_steps += h
-                rep.slot_steps += B * h
-                rep.useful_slot_steps += served * h
-            rep.peak_live_pages = max(
-                rep.peak_live_pages,
-                sum(a.live_count for a in allocs.values()))
-            rep.peak_live_page_bytes = max(
-                rep.peak_live_page_bytes,
-                sum(a.live_count * self.backends[m].page_bytes
-                    for m, a in allocs.items()))
-            rep.peak_demand_pages = max(
-                rep.peak_demand_pages,
-                sum(a.demand_count for a in allocs.values()))
-            rep.peak_demand_page_bytes = max(
-                rep.peak_demand_page_bytes,
-                sum(a.demand_count * self.backends[m].page_bytes
-                    for m, a in allocs.items()))
-        elif not sched.exhausted:
-            nxt = sched.next_arrival()
-            if nxt is not None and nxt > self.step \
-                    and not sched.ready_models():
-                self.step = nxt     # idle: fast-forward to next arrival
-                return True
-            # ready work exists but is blocked (deferred activation /
-            # page wait / an in-flight layer stream): let time pass
-        else:
-            return False            # drained: idle until more is injected
-
-        # -- layer-stream progress: one step of DMA bandwidth --------
+    def _policy_end(self, h: int, decoded: list[_Tenant]) -> None:
+        e, pool, rep = self.ecfg, self.pool, self._rep
+        for t in decoded:
+            # bounded slab: queue this burst's re-stream bytes
+            pool.note_decode_burst(t.model_id)
+        # -- layer-stream progress: one step of DMA bandwidth
         if e.stream == "layer" and pool.streaming:
-            if not did_compute:
+            if not decoded:
                 # prefetch miss: no decode work hides the DMA, so the
                 # engine idles a step waiting on the stream head
                 head = pool.stream_head
                 rep.stall_steps += 1
                 rep.stall_steps_by_model[head] += 1
             pool.stream_tick()      # one step of the DMA channel's clock
-
-        # -- arena bookkeeping: watermarks + epoch repartition -------
+        # -- arena bookkeeping: watermarks + epoch repartition
         # Shrink floor: an ADMITTED request was judged feasible against
         # its tenant's lease at admission; repartitioning must never cut
         # the lease below what the largest in-flight request still needs
         # to finish, or admission feasibility silently stops implying
         # completability (lease churn strands requests in preempt loops)
-        for m in self.page_split:
-            floor = 0
-            for s in range(B):
-                r = slots[s]
-                if r is None or r.model_id != m:
-                    continue
-                R = self.backends[m].ring_rows
-                demand = self._pgr[m].pages_for(
-                    len(r.prompt) + r.max_new_tokens - 1)
-                floor = max(floor, min(demand, R) if R else demand)
+        floors = dict.fromkeys(self.page_split, 0)
+        for r, t in zip(self._slots, self._owner):
+            if r is None or t.alloc is None:
+                continue
+            R = t.backend.ring_rows
+            demand = t.pgr.pages_for(len(r.prompt) + r.max_new_tokens - 1)
+            floors[t.model_id] = max(floors[t.model_id],
+                                     min(demand, R) if R else demand)
+        for m, floor in floors.items():
             self.arena.set_demand_floor(m, floor)
         self.arena.sample()
         if self.arena.maybe_repartition(self.step) is not None:
@@ -2006,40 +1744,7 @@ class PooledEngine:
             # invariants maybe_repartition already asserted
             self.arena.check(slab_used=pool.slab_used,
                              pinned_bytes=pool.plan.pinned_bytes)
-
-        self.step += 1
-        self._rr_left -= 1
-        if self.step > e.max_steps:
-            raise RuntimeError("pooled engine exceeded max_steps")
-        return True
-
-    def finish_run(self) -> PooledReport:
-        pool, rep = self.pool, self._rep
-        for m, sharer in self._sharers.items():
-            sharer.index.release_all(self._allocs[m])
-        self.arena.check(slab_used=pool.slab_used,
-                         pinned_bytes=pool.plan.pinned_bytes)
-        for a in self._allocs.values():
-            assert a.live_count == 0, "pages leaked past completion"
-        rep.preemptions = self._sched.preemptions
-        rep.reload_bytes = pool.reload_bytes_total
-        rep.restream_bytes = pool.restream_bytes_total
-        rep.reload_events = pool.reload_events
-        rep.evictions = pool.evictions
-        rep.deferred_activations = pool.deferred_activations
-        rep.repartitions = self.arena.repartitions
-        rep.pages_moved = self.arena.pages_moved
-        rep.device_dispatches = self._ds.device_dispatches
-        rep.host_syncs = self._ds.host_syncs
-        rep.page_table_upload_bytes = self._ds.page_table_upload_bytes
-        rep.wall_s = time.monotonic() - self._t_run
-        return rep
-
-    def run(self, requests: list[Request]) -> PooledReport:
-        self.start(requests)
-        while self.step_once():
-            pass
-        return self.finish_run()
+        self._rr_left -= h
 
 
 # --- static lockstep baseline --------------------------------------------------

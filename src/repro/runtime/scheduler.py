@@ -12,6 +12,7 @@ re-prefills prompt + generated tokens when pages free up).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from collections import deque
 from collections.abc import Sequence
 
@@ -217,68 +218,27 @@ def diurnal_trace(tenants: Sequence[dict], n_requests: int, *,
 
 
 class Scheduler:
-    """FCFS admission queue over an arrival trace + preemption policy."""
+    """Per-model FCFS admission queues over one arrival trace, and the
+    preemption policy.
 
-    def __init__(self, requests: list[Request]):
-        self._pending = deque(sorted(requests, key=lambda r: r.arrival))
-        self._ready: deque[Request] = deque()
-        self.preemptions = 0
-
-    # -- arrival handling ---------------------------------------------------
-
-    def release_arrivals(self, step: int) -> None:
-        while self._pending and self._pending[0].arrival <= step:
-            self._ready.append(self._pending.popleft())
-
-    def next_arrival(self) -> int | None:
-        return self._pending[0].arrival if self._pending else None
-
-    # -- admission ----------------------------------------------------------
-
-    def peek_ready(self) -> Request | None:
-        return self._ready[0] if self._ready else None
-
-    def pop_ready(self) -> Request:
-        return self._ready.popleft()
-
-    def requeue(self, req: Request) -> None:
-        """Preempted request: back to the queue head (it keeps priority)."""
-        self._ready.appendleft(req)
-        self.preemptions += 1
-
-    # -- preemption policy --------------------------------------------------
-
-    @staticmethod
-    def pick_victim(active: list[tuple[int, Request]],
-                    exclude: int | None = None) -> tuple[int, Request] | None:
-        """Latest-admitted active request (slot, request); optionally
-        excluding one slot (the one whose growth triggered the hunt)."""
-        cands = [(s, r) for s, r in active if s != exclude]
-        if not cands:
-            cands = [(s, r) for s, r in active]
-        if not cands:
-            return None
-        return max(cands, key=lambda sr: (sr[1].admitted_step, sr[0]))
-
-    @property
-    def exhausted(self) -> bool:
-        return not self._pending and not self._ready
-
-
-class MultiQueueScheduler:
-    """Per-model FCFS queues over one merged arrival trace.
-
-    Admission stays FCFS *within* a model; across models the pool engine
-    chooses which queues are servable (their weights are hot) and this
-    scheduler hands out the earliest-arrived ready request among them.
-    Preempted requests go back to their model's queue head.
+    Requests are queued by ``model_id``. Admission stays FCFS within a
+    model; across models the engine names the queues it may admit from
+    (one tenant's engine admits from all of them) and takes the earliest
+    ready head among them. Ties are ordered by arrival, then by the order
+    the requests were handed in (``__init__`` or ``inject``). A preempted
+    request goes back to the head of its model's queue.
     """
 
     def __init__(self, requests: list[Request]):
-        self._pending = deque(sorted(requests,
-                                     key=lambda r: (r.arrival, r.rid)))
+        self._pending: deque[Request] = deque()
         self._ready: dict[str, deque[Request]] = {}
+        self._seq: dict[int, int] = {}     # id(request) -> hand-in order
+        self._count = itertools.count()
         self.preemptions = 0
+        self.inject(requests)
+
+    def _key(self, r: Request) -> tuple[int, int]:
+        return r.arrival, self._seq[id(r)]
 
     # -- arrival handling ---------------------------------------------------
 
@@ -291,22 +251,27 @@ class MultiQueueScheduler:
         return self._pending[0].arrival if self._pending else None
 
     def inject(self, requests: list[Request]) -> None:
-        """Add requests mid-run (the fleet router dispatches this way:
-        arrivals are stamped with the replica's CURRENT step, so they
-        release on the next scan). Pending order stays (arrival, rid)."""
-        merged = sorted(list(self._pending) + list(requests),
-                        key=lambda r: (r.arrival, r.rid))
-        self._pending = deque(merged)
+        """Add requests, also mid-run (the fleet router dispatches this
+        way: arrivals are stamped with the replica's CURRENT step, so they
+        release on the next scan)."""
+        for r in requests:
+            self._seq[id(r)] = next(self._count)
+        self._pending = deque(sorted([*self._pending, *requests],
+                                     key=self._key))
 
     def drain(self) -> list[Request]:
         """Pull every queued request (ready + pending) out of the
         scheduler — the failover path: a killed replica's queue is
-        re-admitted elsewhere. Returns them in (arrival, rid) order."""
+        re-admitted elsewhere. Returns them in queue order."""
         out = [r for q in self._ready.values() for r in q]
         out += list(self._pending)
         self._ready.clear()
         self._pending.clear()
-        return sorted(out, key=lambda r: (r.arrival, r.rid))
+        return sorted(out, key=self._key)
+
+    def __len__(self) -> int:
+        """Requests queued, ready or still to arrive."""
+        return len(self._pending) + sum(map(len, self._ready.values()))
 
     # -- admission ----------------------------------------------------------
 
@@ -322,18 +287,20 @@ class MultiQueueScheduler:
         return sum(r.max_new_tokens - len(r.generated)
                    for r in self._ready.get(model_id, ()))
 
-    def ready_heads(self, allowed: Sequence[str]) -> list[Request]:
-        """Queue heads of the allowed models, earliest arrival first.
-        Admission walks this list so a tenant waiting on its own page
-        sub-range does not block its neighbours (FCFS stays per-model)."""
-        allowed = set(allowed)
+    def ready_heads(self, allowed: Sequence[str] | None = None
+                    ) -> list[Request]:
+        """Queue heads of the allowed models (every model if None),
+        earliest first. Admission walks this list so a tenant waiting on
+        its own pages need not block its neighbours."""
         heads = [q[0] for m, q in self._ready.items()
-                 if q and m in allowed]
-        heads.sort(key=lambda r: (r.arrival, r.rid))
+                 if q and (allowed is None or m in allowed)]
+        if len(heads) > 1:
+            heads.sort(key=self._key)
         return heads
 
-    def peek_ready(self, allowed: Sequence[str]) -> Request | None:
-        """Earliest-arrival ready request among the allowed models."""
+    def peek_ready(self, allowed: Sequence[str] | None = None
+                   ) -> Request | None:
+        """Earliest ready request among the allowed models."""
         heads = self.ready_heads(allowed)
         return heads[0] if heads else None
 
@@ -342,18 +309,36 @@ class MultiQueueScheduler:
         which models are servable right now) — the engine turns this
         into a queued-age signal the fleet router ties on, so a replica
         with a long-stuck head stops winning new traffic on load alone."""
-        heads = [q[0] for q in self._ready.values() if q]
-        return min((r.arrival for r in heads), default=None)
+        return min((q[0].arrival for q in self._ready.values() if q),
+                   default=None)
 
-    def pop_ready(self, req: Request) -> Request:
+    def pop_ready(self, req: Request | None = None) -> Request:
+        """Take ``req`` (default: the earliest ready head) off its queue;
+        it must be that queue's head."""
+        req = req if req is not None else self.peek_ready()
         got = self._ready[req.model_id].popleft()
         assert got is req, "pop must follow peek"
         return got
 
     def requeue(self, req: Request) -> None:
-        """Preempted request: back to its model's queue head."""
+        """Preempted request: back to its model's queue head (it keeps
+        priority)."""
         self._ready.setdefault(req.model_id, deque()).appendleft(req)
         self.preemptions += 1
+
+    # -- preemption policy --------------------------------------------------
+
+    @staticmethod
+    def pick_victim(active: list[tuple[int, Request]],
+                    exclude: int | None = None) -> tuple[int, Request] | None:
+        """Latest-admitted active request (slot, request); optionally
+        excluding one slot (the one whose growth triggered the hunt)."""
+        cands = [(s, r) for s, r in active if s != exclude]
+        if not cands:
+            cands = [(s, r) for s, r in active]
+        if not cands:
+            return None
+        return max(cands, key=lambda sr: (sr[1].admitted_step, sr[0]))
 
     @property
     def exhausted(self) -> bool:

@@ -1,10 +1,11 @@
 """Spans and counters of the engine loop.
 
-``Engine.run`` writes its spans as ``jax.profiler`` trace annotations:
-while a profiler runs they land on its host plane, on the clock of the
-device lines, so a reader can lay what the host did beside what the chip
-did. With no profiler running a span costs about a microsecond, and its
-keyword arguments are not formatted. The spans:
+The engine loop (``Engine.step_once``, which ``Engine.run`` and
+``PooledEngine`` both run) writes its spans as ``jax.profiler`` trace
+annotations: while a profiler runs they land on its host plane, on the
+clock of the device lines, so a reader can lay what the host did beside
+what the chip did. With no profiler running a span costs about a
+microsecond, and its keyword arguments are not formatted. The spans:
 
   engine.step     one loop iteration (a step trace event, ``step_num``)
   engine.admit    one request's admission: scheduler pick, pages, prefill,
@@ -13,7 +14,8 @@ keyword arguments are not formatted. The spans:
                   logits on the host
   engine.grow     page growth, copy-on-write and preemption
   engine.sync     the upload of the loop state's dirty rows
-  engine.decode   the decode dispatch, fused or per-step
+  engine.decode   the decode dispatch, fused or per-step (one per tenant
+                  that decodes)
   engine.wait     the host blocked on the dispatch's tokens or logits
   engine.deliver  handing the tokens to requests, finishing the done ones
 
@@ -45,9 +47,10 @@ CAPACITY = 1 << 16
 
 # what bound a decode dispatch's horizon: the horizon cap, a slot's next
 # page boundary, a request's last token, a free slot with work queued
-# (admission retried every step), the next arrival; ``unfused`` is the
-# per-step path
-CAUSES = ("cap", "page", "finish", "admit", "arrival", "unfused")
+# (admission retried every step), the next arrival, the pooled engine's
+# policy (round-robin quantum, weight streaming, a cold tenant, an arena
+# epoch); ``unfused`` is the per-step path
+CAUSES = ("cap", "page", "finish", "admit", "arrival", "policy", "unfused")
 
 
 class Ring:

@@ -11,9 +11,8 @@ from repro.configs import get_config
 from repro.models import get_model
 from repro.planner.residency import (double_buffer_bytes, layer_schedule,
                                      quant_bytes, weight_inventory)
-from repro.runtime import (ModelPool, MultiQueueScheduler, PoolConfig,
-                           PoolEngineConfig, PoolError, PooledEngine,
-                           Request, multi_tenant_trace, partition_pages,
+from repro.runtime import (ModelPool, PoolConfig, PoolEngineConfig,
+                           PoolError, PooledEngine, Request, Scheduler, multi_tenant_trace, partition_pages,
                            poisson_trace, shifting_mix_trace,
                            vlm_extras_fn)
 
@@ -314,7 +313,7 @@ def test_multi_queue_scheduler_fcfs_across_models():
                     arrival=0, model_id="b"),
             Request(rid=2, prompt=np.zeros(4, np.int32), max_new_tokens=2,
                     arrival=1, model_id="a")]
-    s = MultiQueueScheduler(reqs)
+    s = Scheduler(reqs)
     s.release_arrivals(0)
     assert s.ready_models() == ["b"]
     assert s.peek_ready(["a"]) is None

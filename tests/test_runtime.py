@@ -9,8 +9,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import get_model, transformer as T
-from repro.runtime import (Engine, EngineConfig, MultiQueueScheduler,
-                           NEUTRAL_OWNER, PageAllocator, PagerConfig,
+from repro.runtime import (Engine, EngineConfig, NEUTRAL_OWNER,
+                           PageAllocator, PagerConfig,
                            PrefixIndex, Request, Scheduler, poisson_trace,
                            run_static, shared_prefix_trace)
 
@@ -450,7 +450,7 @@ def test_multi_queue_scheduler_oldest_ready_arrival():
     mk = lambda rid, arr, m: Request(
         rid=rid, prompt=np.zeros(4, np.int32), max_new_tokens=4,
         arrival=arr, model_id=m)
-    s = MultiQueueScheduler([mk(0, 2, "a"), mk(1, 5, "b"), mk(2, 9, "a")])
+    s = Scheduler([mk(0, 2, "a"), mk(1, 5, "b"), mk(2, 9, "a")])
     assert s.oldest_ready_arrival() is None
     s.release_arrivals(6)
     assert s.oldest_ready_arrival() == 2    # head of a's queue

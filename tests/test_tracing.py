@@ -1,7 +1,8 @@
 """The engine loop's spans and counters (runtime/tracing.py): the counter
 ring's bounds and time clipping, the cause recorded for each cut of the
 fused horizon, the decode tokens the dispatch samples account for, and the
-span names a profiler trace of ``Engine.run`` holds."""
+span names a profiler trace of the loop holds, run by ``Engine`` and by a
+two-tenant ``PooledEngine``."""
 
 import copy
 import time
@@ -13,7 +14,9 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import get_model
-from repro.runtime import Engine, EngineConfig, Request, poisson_trace
+from repro.runtime import (Engine, EngineConfig, ModelPool, PoolConfig,
+                           PoolEngineConfig, PooledEngine, Request,
+                           multi_tenant_trace, poisson_trace)
 from repro.runtime import tracing
 
 SPANS = {"engine.step", "engine.admit", "engine.prefill", "engine.grow",
@@ -36,6 +39,24 @@ def _req(rid, plen, gen, vocab, arrival=0):
     prompt = np.random.default_rng(rid).integers(0, vocab, plen)
     return Request(rid=rid, prompt=prompt.astype(np.int32),
                    max_new_tokens=gen, arrival=arrival)
+
+
+def _engine_and_trace(kind, dense, recurrent, n, kw, lens):
+    """``Engine`` over the dense model with a Poisson trace of ``n``
+    requests, or a pool of the dense and the recurrent model, both
+    resident, with a trace that interleaves them."""
+    (dcfg, dparams), (rcfg, rparams) = dense, recurrent
+    if kind == "engine":
+        return (Engine(dcfg, dparams, EngineConfig(**kw)),
+                poisson_trace(n, vocab_size=dcfg.vocab_size, **lens))
+    pool = ModelPool(PoolConfig(hbm_budget_bytes=1 << 30))
+    pool.register("dense", dcfg)
+    pool.register("recurrent", rcfg)
+    engine = PooledEngine(pool, {"dense": dparams, "recurrent": rparams},
+                          PoolEngineConfig(**kw))
+    tenants = [dict(model_id="dense", vocab_size=dcfg.vocab_size),
+               dict(model_id="recurrent", vocab_size=rcfg.vocab_size)]
+    return engine, multi_tenant_trace(tenants, n, **lens)
 
 
 def _dispatches(engine, requests):
@@ -134,23 +155,28 @@ def test_first_dispatch_records_its_cut(dense, recurrent, cause):
 
 
 @pytest.mark.parametrize("horizon", [1, 16])
-def test_dispatches_account_for_every_decode_token(dense, horizon):
+@pytest.mark.parametrize("kind", ["engine", "pool"])
+def test_dispatches_account_for_every_decode_token(dense, recurrent, kind,
+                                                   horizon):
     """Sum of h x live over the dispatches equals the tokens the requests
-    received from decode: all but each request's first, from prefill."""
-    cfg, params = dense
-    ecfg = EngineConfig(num_slots=4, page_size=8, num_pages=33,
-                        max_pages_per_seq=8, prefill_bucket=8,
-                        horizon=horizon)
-    trace = poisson_trace(8, mean_interarrival=0.5, prompt_lens=(6, 10),
-                          gen_lens=(3, 9, 20), vocab_size=cfg.vocab_size,
-                          seed=4)
-    rep, got = _dispatches(Engine(cfg, params, ecfg), copy.deepcopy(trace))
+    received from decode: all but each request's first, from prefill. The
+    pool makes one dispatch per tenant that decodes in a step."""
+    engine, trace = _engine_and_trace(
+        kind, dense, recurrent, 8,
+        dict(num_slots=4, page_size=8, num_pages=33, max_pages_per_seq=8,
+             prefill_bucket=8, horizon=horizon),
+        dict(mean_interarrival=0.5, prompt_lens=(6, 10),
+             gen_lens=(3, 9, 20), seed=4))
+    rep, got = _dispatches(engine, copy.deepcopy(trace))
     assert rep.preemptions == 0
     decoded = sum(len(r.generated) - 1 for r in rep.completed)
     assert sum(s.h * s.live for s in got) == decoded
-    assert sum(s.h for s in got) == rep.decode_steps
-    assert len(got) == rep.decode_steps if horizon == 1 else \
-        len(got) < rep.decode_steps
+    steps = sum(s.h for s in got)
+    if kind == "engine":
+        assert steps == rep.decode_steps
+    else:                               # the tenants shared some steps
+        assert rep.decode_steps < steps <= 2 * rep.decode_steps
+    assert len(got) == steps if horizon == 1 else len(got) < steps
 
 
 def test_prefills_record_prompt_and_bucket(dense):
@@ -167,16 +193,16 @@ def test_prefills_record_prompt_and_bucket(dense):
 # --- spans ---------------------------------------------------------------------
 
 
-def test_profiler_trace_holds_every_span(dense, tmp_path):
+@pytest.mark.parametrize("kind", ["engine", "pool"])
+def test_profiler_trace_holds_every_span(dense, recurrent, kind, tmp_path):
     from jax.profiler import ProfileData
 
-    cfg, params = dense
-    ecfg = EngineConfig(num_slots=2, page_size=8, num_pages=33,
-                        max_pages_per_seq=8, prefill_bucket=8)
-    trace = poisson_trace(4, mean_interarrival=0.5, prompt_lens=(6, 10),
-                          gen_lens=(3, 12), vocab_size=cfg.vocab_size,
-                          seed=1)
-    engine = Engine(cfg, params, ecfg)
+    engine, trace = _engine_and_trace(
+        kind, dense, recurrent, 4,
+        dict(num_slots=2, page_size=8, num_pages=33, max_pages_per_seq=8,
+             prefill_bucket=8),
+        dict(mean_interarrival=0.5, prompt_lens=(6, 10), gen_lens=(3, 12),
+             seed=1))
     engine.run(copy.deepcopy(trace))      # compile outside the trace
     jax.profiler.start_trace(str(tmp_path))
     try:
